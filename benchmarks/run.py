@@ -96,7 +96,7 @@ from repro.core.canny.hysteresis import (
 )
 from repro.core.canny.nms import nms_stage
 from repro.core.canny.sobel import sobel_stage
-from repro.core.patterns.dist import Dist, StencilCtx
+from repro.core.patterns.dist import Dist, StencilCtx, auto_mesh
 from repro.core.patterns.partition import tile_counts
 from repro.data.images import synthetic_batch, synthetic_image
 from repro.kernels.fused_canny.ops import fused_canny
@@ -405,9 +405,9 @@ def _sharded_payload(h=256, w=256, b=8):
     local_out = np.asarray(fused_canny(imgs, *args))
     exact = True
     meshes = {
-        "data8": (jax.make_mesh((8,), ("data",)), ("data",), None),
+        "data8": (auto_mesh((8,), ("data",)), ("data",), None),
         "data2model4": (
-            jax.make_mesh((2, 4), ("data", "model")), ("data",), "model",
+            auto_mesh((2, 4), ("data", "model")), ("data",), "model",
         ),
     }
     for name, (mesh, batch_axes, space) in meshes.items():
@@ -433,9 +433,19 @@ def sharded_throughput():
     and its CSV rows are folded into this process's table. Interpret-mode
     CPU numbers measure composition overhead, not TPU speedups — the
     headline is the bit-exactness row plus the scaling shape.
+
+    Refused on a TPU host: this process already holds the chip, and a
+    chip belongs to one process at a time, so the child would fail or
+    hang trying to open it.
     """
     import os
 
+    if jax.devices()[0].platform == "tpu":
+        raise RuntimeError(
+            "sharded_throughput measures forced CPU devices in a child "
+            "process, but this process already holds the TPU and a chip "
+            "serves one process at a time; run it on a CPU host"
+        )
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     proc = subprocess.run(
@@ -515,7 +525,7 @@ def _bench_mesh_dist() -> Dist:
     n = len(jax.devices())
     data = 2 if n >= 2 else 1
     model = max(d for d in (1, 2, 4) if data * d <= n)
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = auto_mesh((data, model), ("data", "model"))
     return Dist(mesh=mesh, batch_axes=("data",), space_axis="model")
 
 
@@ -1124,6 +1134,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--sharded-payload" in sys.argv:
         print("name,us_per_call,derived")
         _sharded_payload()

@@ -43,6 +43,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Iterator
 
+import jax
+
 
 class UnsupportedFeature(ValueError):
     """A backend was asked for a feature its BackendSpec does not claim."""
@@ -141,12 +143,47 @@ def register_backend_spec(spec: BackendSpec, override: bool = False) -> BackendS
 
 
 def _load_kernel_specs() -> None:
-    """Import the kernel package's registrations once (no hard Pallas dep:
-    the jnp spec keeps working when the import fails)."""
-    try:
-        import repro.kernels.canny_backends  # noqa: F401  (registers)
-    except ImportError:  # pragma: no cover - exercised without Pallas
-        pass
+    """Import the kernel package's registrations (idempotent)."""
+    import repro.kernels.canny_backends  # noqa: F401  (registers)
+
+
+def _platform() -> str:
+    return jax.devices()[0].platform
+
+
+def default_backend(cpu_default: str) -> str:
+    """The Canny backend an entry point runs when its caller names none.
+
+    On a TPU every entry point runs the fused Pallas kernel. Elsewhere
+    each entry keeps its own portable default (``cpu_default``: ``"jnp"``
+    for the detector/plan builders, ``"fused"`` for the serving and
+    streaming planes, which the CPU suite drives in interpret mode).
+    """
+    return "fused" if _platform() == "tpu" else cpu_default
+
+
+def op_backend(op: str, backend: str | None, cpu_default: str) -> str:
+    """The backend computing operator ``op``: an explicit ``backend`` is
+    validated against ``op`` (a detector never silently computes another
+    operator); ``None`` resolves Canny through ``default_backend`` and a
+    zoo operator to its registered backend."""
+    if backend is not None:
+        spec = backend_spec(backend)
+        if spec.op != op:
+            raise ValueError(
+                f"backend {backend!r} computes operator {spec.op!r}, "
+                f"not {op!r}"
+            )
+        return backend
+    if op == "canny":
+        return default_backend(cpu_default)
+    candidates = [s.name for s in backend_specs() if s.op == op]
+    if not candidates:
+        raise ValueError(
+            f"no backend registered for operator {op!r} (registered "
+            f"operators: {sorted({s.op for s in backend_specs()})})"
+        )
+    return candidates[0]
 
 
 def backend_spec(name: str) -> BackendSpec:

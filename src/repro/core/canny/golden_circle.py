@@ -24,6 +24,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.core.canny.backends import default_backend
 from repro.core.canny.params import CannyParams
 from repro.core.canny.pipeline import make_canny
 from repro.core.patterns.dist import Dist
@@ -65,9 +66,7 @@ def plan(
     space_axis: str | None = "model",
 ) -> CannyPlan:
     """Shell layer: choose a schedule and verify its balance invariant."""
-    if backend is None:
-        platform = jax.devices()[0].platform
-        backend = "fused" if platform == "tpu" else "jnp"
+    backend = backend or default_backend("jnp")
 
     if mesh is None:
         dist = Dist()

@@ -26,11 +26,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.canny.backends import (
     BackendSpec,
     UnsupportedFeature,
     backend_spec,
+    default_backend,
+    op_backend,
     register_backend_spec,
     _SPECS,
 )
@@ -134,7 +135,7 @@ def _resolve_stage_fn(backend: str) -> Callable:
 def make_canny(
     params: CannyParams = CannyParams(),
     dist: Dist = Dist(),
-    backend: str = "jnp",
+    backend: str | None = None,
     local_sweeps: int = 2,
     bucket_multiple: int | None = 64,
 ) -> Callable[[jax.Array], jax.Array]:
@@ -160,6 +161,7 @@ def make_canny(
             "farm of them — use FarmScheduler(dist=...) or stream/pod.py "
             "with per-rank Dist.pod_slice"
         )
+    backend = backend or default_backend("jnp")
     spec = backend_spec(backend)
     if not dist.is_local:
         spec.require(dist=True)
@@ -198,7 +200,7 @@ def make_canny(
         else:
             raise ValueError(f"expected (h,w) or (b,h,w); got ndim={ndim}")
 
-        local = compat.shard_map(
+        local = jax.shard_map(
             lambda x: stage_fn(x, params, ctx, local_sweeps=local_sweeps)
             if stage_fn is canny_local_stages
             else stage_fn(x, params, ctx),
@@ -241,31 +243,16 @@ def make_detector(
 ) -> Callable[[jax.Array], jax.Array]:
     """Operator-aware ``make_canny``: resolve ``op`` through the registry.
 
-    ``backend=None`` picks the operator's registered backend (``"jnp"``
-    for Canny — the portable default — and the sole registered spec for
-    each zoo operator); an explicit ``backend`` is validated against
-    ``op`` so a detector never silently computes a different operator
-    than it was asked for. Everything downstream — buckets, mesh,
-    capability validation — is ``make_canny``, one construction path for
-    the whole zoo.
+    ``backend=None`` picks the operator's default backend
+    (``backends.op_backend``: fused for Canny on a TPU, ``"jnp"`` — the
+    portable path — elsewhere, and the registered spec for each zoo
+    operator); an explicit ``backend`` is validated against ``op`` so a
+    detector never silently computes a different operator than it was
+    asked for. Everything downstream — buckets, mesh, capability
+    validation — is ``make_canny``, one construction path for the whole
+    zoo.
     """
-    from repro.core.canny.backends import backend_specs
-
-    if backend is None:
-        candidates = [s.name for s in backend_specs() if s.op == op]
-        if not candidates:
-            raise ValueError(
-                f"no backend registered for operator {op!r} "
-                f"(registered operators: {registered_ops()})"
-            )
-        backend = "jnp" if op == "canny" else candidates[0]
-    else:
-        spec = backend_spec(backend)
-        if spec.op != op:
-            raise ValueError(
-                f"backend {backend!r} computes operator {spec.op!r}, "
-                f"not {op!r}"
-            )
+    backend = op_backend(op, backend, cpu_default="jnp")
     return make_canny(
         params,
         dist,
@@ -279,7 +266,7 @@ def canny(
     img: jax.Array,
     params: CannyParams = CannyParams(),
     dist: Dist = Dist(),
-    backend: str = "jnp",
+    backend: str | None = None,
 ) -> jax.Array:
     """One-shot convenience wrapper around ``make_canny``."""
     return make_canny(params, dist, backend)(img)

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.canny.gaussian import gaussian_stage
 from repro.core.canny.hysteresis import hysteresis_stage
 from repro.core.canny.nms import nms_stage
@@ -85,7 +84,7 @@ def jnp_serving(
         off = lax.axis_index(space) * (hp // ms) if space is not None else 0
         return _true_size_block(x, hw, params, ectx, zctx, off, local_sweeps=2)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=dist.mesh,
         in_specs=(dist.batch_spec(), dist.table_spec()),

@@ -18,9 +18,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
-from repro import compat
+
+def auto_mesh(axis_shapes, axis_names, devices=None) -> Mesh:
+    """Every mesh this repo builds: all axes ``AxisType.Auto``.
+
+    The patterns place data through ``shard_map`` specs and leave the
+    rest to the partitioner; ``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which plain slicing of a sharded dim (e.g. cropping the
+    global row padding) is refused. ``devices`` — a flat list or an
+    array of the right size — pins the mesh to those devices.
+    """
+    shape, names = tuple(axis_shapes), tuple(axis_names)
+    types = (AxisType.Auto,) * len(names)
+    if devices is None:
+        return jax.make_mesh(shape, names, axis_types=types)
+    return Mesh(np.asarray(devices).reshape(shape), names, axis_types=types)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,7 +98,7 @@ class Dist:
         rest = tuple(a for a in names if a != self.pod_axis)
         if devs.size == 1:
             return Dist()
-        sub = Mesh(devs, rest)
+        sub = auto_mesh(devs.shape, rest, devs)
         batch = tuple(a for a in self.batch_axes if sub.shape.get(a, 1) > 1)
         space = self.space_axis
         if space is not None and sub.shape.get(space, 1) == 1:
@@ -187,7 +201,7 @@ class StencilCtx:
         """sync_axes minus trivial (size-1) mesh axes — a psum over a
         size-1 axis is an identity that still costs a collective, so
         consensus no-ops cheaply on them (and on an all-trivial mesh)."""
-        return tuple(a for a in self.sync_axes if compat.axis_size(a) > 1)
+        return tuple(a for a in self.sync_axes if lax.axis_size(a) > 1)
 
     def any_global(self, flag: jax.Array) -> jax.Array:
         """OR-reduce a boolean across ALL sync axes (identity locally)."""
@@ -221,7 +235,7 @@ def _halo_exchange(
     sharded stencil bit-identical to the unsharded one.
     """
     axis = axis % x.ndim
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return _pad_axis(x, halo, axis, pad_mode)
 

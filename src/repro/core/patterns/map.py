@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 from repro.core.patterns.dist import Dist
 
@@ -36,7 +35,7 @@ def pattern_map(fn: Callable, dist: Dist = Dist()) -> Callable:
     @jax.jit
     def run(*args):
         args = tuple(jax.device_put(a, sharding) for a in args)
-        shard_fn = compat.shard_map(
+        shard_fn = jax.shard_map(
             fn, mesh=dist.mesh, in_specs=spec, out_specs=spec, check_vma=False
         )
         return shard_fn(*args)

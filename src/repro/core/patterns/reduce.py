@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 from repro.core.patterns.dist import Dist
 
@@ -49,7 +48,7 @@ def pattern_reduce(kind: str, dist: Dist = Dist()) -> Callable:
     @jax.jit
     def run(x):
         x = jax.device_put(x, NamedSharding(dist.mesh, spec))
-        shard_fn = compat.shard_map(
+        shard_fn = jax.shard_map(
             lambda xl: cross(local(xl), axes),
             mesh=dist.mesh,
             in_specs=spec,

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 
 T = TypeVar("T")
 
@@ -89,7 +88,7 @@ def pattern_scan(
     if axis_name is None:
         return local
 
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return local
 

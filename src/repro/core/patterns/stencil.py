@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 from repro.core.patterns.dist import Dist, StencilCtx, _halo_exchange, _pad_axis
 
@@ -109,7 +108,7 @@ def stencil2d(
     def run(x):
         sharding = NamedSharding(dist.mesh, ndim_specs)
         x = jax.device_put(x, sharding)
-        shard_fn = compat.shard_map(
+        shard_fn = jax.shard_map(
             lambda xl: fn(xl, ctx),
             mesh=dist.mesh,
             in_specs=ndim_specs,

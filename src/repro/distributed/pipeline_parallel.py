@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 
 def pipeline_apply(
@@ -41,7 +40,7 @@ def pipeline_apply(
       may pass zeros; only stage 0's values enter the pipe).
     Returns (M, mb, ...) outputs — meaningful on the LAST stage.
     """
-    n_stages = compat.axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     # shard_map leaves a leading (1, ...) stage dim on the params — drop it
     stage_params = jax.tree_util.tree_map(
@@ -95,7 +94,7 @@ def make_pipelined_fn(
     """
     dspec = data_spec if data_spec is not None else P()
 
-    inner = compat.shard_map(
+    inner = jax.shard_map(
         lambda p, x: pipeline_apply(stage_fn, p, x, stage_axis),
         mesh=mesh,
         in_specs=(P(stage_axis), dspec),

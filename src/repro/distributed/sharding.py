@@ -17,27 +17,12 @@ Parallelism expressed purely through these rules:
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import math
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.common import ParamSpec, logical_axes
-
-
-def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """``jax.sharding.AbstractMesh`` across the constructor signature drift.
-
-    Older jax (≤0.4.x) takes ``shape_tuple=((name, size), ...)``; newer
-    takes ``(axis_sizes, axis_names)``. Passing sizes to the old form dies
-    deep in ``jax/_src/mesh.py`` with "TypeError: 'int' object is not
-    iterable" — construct whichever form this jax expects.
-    """
-    cls = jax.sharding.AbstractMesh
-    if "shape_tuple" in inspect.signature(cls.__init__).parameters:
-        return cls(tuple(zip(axis_names, axis_sizes)))
-    return cls(tuple(axis_sizes), tuple(axis_names))
 
 
 @dataclasses.dataclass(frozen=True)

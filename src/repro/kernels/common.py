@@ -32,6 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def on_tpu() -> bool:
@@ -51,41 +52,55 @@ def pick_block_rows(h: int, target: int = 128, min_rows: int = 1) -> int:
     return max(min(h, target), min_rows)
 
 
+SUBLANES = 8  # a strip height the TPU kernel compiler tiles for any strip count
+
+
 def pick_block_rows_divisor(h: int, target: int = 128, min_rows: int = 1) -> int:
     """Strip height that exactly divides ``h`` — the shard-local variant.
 
     Inside ``shard_map`` a shard cannot pad its own rows (local pad rows
     would land BETWEEN shards, breaking global row adjacency), so the
     strip height must divide the shard-local height exactly. Returns the
-    largest divisor of ``h`` that is ≤ ``target`` and ≥ ``min_rows``.
+    largest divisor of ``h`` that is ≤ ``target`` and ≥ ``min_rows``,
+    preferring multiples of ``SUBLANES``: the chip takes a strip shorter
+    than its array only when it is sublane-aligned.
     """
     if h < min_rows:
         raise ValueError(
             f"shard-local height {h} smaller than the stage halo {min_rows}; "
             "use fewer row shards or a larger image"
         )
-    for bh in range(min(h, target), min_rows - 1, -1):
-        if h % bh == 0:
-            return bh
-    return h  # h itself always divides (single strip per shard)
+    fits = [d for d in range(min(h, target), min_rows - 1, -1) if h % d == 0]
+    aligned = [d for d in fits if d % SUBLANES == 0]
+    # h itself always divides (a single strip per shard)
+    return (aligned or fits or [h])[0]
 
 
-def pick_batch_block(
-    b: int,
-    bh: int,
-    w: int,
-    budget_bytes: int | None = None,
-    live_buffers: int = 10,
-) -> int:
-    """Images per kernel instance (the BT block dim). Largest divisor of
-    ``b`` whose working set (≈``live_buffers`` f32 strip-sized arrays per
-    image) fits the VMEM budget; interpret mode gets a roomier budget —
-    there the point of BT is amortizing per-grid-cell overhead, not VMEM.
+# Scoped VMEM every strip kernel may claim (v5e has 128 MiB per core; the
+# compiler's default scope is 16 MiB, which a 1080p fused strip already
+# fills). Passed to every pallas_call via ``compiler_params``.
+VMEM_LIMIT_BYTES = 64 << 20
+# Live set per image, in f32 (BH, W-rounded-to-128-lanes) tiles: the most
+# any strip kernel here needs at the smallest limit it compiles under for
+# v5e — measured 12-21 (sobel 20.5, fused 16-19, hysteresis words 17),
+# rounded up.
+LIVE_TILES = 24
+
+
+def compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def pick_batch_block(b: int, bh: int, w: int) -> int:
+    """Images per kernel instance (the BT block dim): the largest divisor
+    of ``b`` whose live set (``LIVE_TILES`` lane-padded f32 strip tiles
+    per image) fits half the VMEM limit on the chip. Interpret mode gets a
+    roomier budget — there the point of BT is amortizing per-grid-cell
+    overhead, not VMEM.
     """
-    if budget_bytes is None:
-        budget_bytes = (8 << 20) if on_tpu() else (256 << 20)
-    per_image = max(bh * w * 4 * live_buffers, 1)
-    bt = max(1, min(b, budget_bytes // per_image))
+    budget = VMEM_LIMIT_BYTES // 2 if on_tpu() else 256 << 20
+    per_image = bh * (-(-w // 128) * 128) * 4 * LIVE_TILES
+    bt = max(1, min(b, budget // per_image))
     while b % bt:
         bt -= 1
     return bt
@@ -139,11 +154,24 @@ def out_strip_spec(bh: int, w: int, bt: int = 1, strip_axis: int = 1):
 
 
 def per_image_spec(cols: int, bt: int = 1, strip_axis: int = 1):
-    """Spec for per-image metadata rows, e.g. the (B, 2) true-size table:
-    every strip of image-block b binds the same (BT, cols) slice."""
+    """Spec for per-image metadata rows, e.g. the true-size table in its
+    (B, 1, cols) kernel layout (``per_image_table``): every strip of
+    image-block b binds the same (BT, 1, cols) slice. The unit sublane
+    dim keeps the block legal for any BT under the (8, 128) rule."""
     if strip_axis == 0:
-        return pl.BlockSpec((bt, cols), lambda i: (0, 0))
-    return pl.BlockSpec((bt, cols), lambda b, i: (b, 0))
+        return pl.BlockSpec((bt, 1, cols), lambda i: (0, 0, 0))
+    return pl.BlockSpec((bt, 1, cols), lambda b, i: (b, 0, 0))
+
+
+def per_image_table(table):
+    """(B, cols) per-image table → the (B, 1, cols) int32 kernel layout."""
+    return table.astype(jnp.int32).reshape(table.shape[0], 1, table.shape[1])
+
+
+def true_sizes(hw_ref):
+    """Kernel side of the true-size table: per-image (height, width),
+    each shaped (BT, 1, 1) to broadcast over a (BT, rows, W) tile."""
+    return hw_ref[:, :, 0:1], hw_ref[:, :, 1:2]
 
 
 def halo_spec(halo: int, w: int, bt: int = 1, strip_axis: int = 1):
@@ -249,11 +277,14 @@ def check_halos(halos, b: int, halo: int, w: int):
 
 
 def strip_map_spec(bt: int = 1, strip_axis: int = 1):
-    """Spec for a per-(image, strip) map — e.g. the hysteresis (B,
-    n_strips) changed counters — one (BT, 1) cell per grid point."""
+    """Spec for a per-(image, strip) map — the hysteresis changed counters,
+    the temporal skip mask — in its (B, n_strips, 1, 1) kernel layout: one
+    (BT, 1, 1, 1) cell per grid point. The unit minor dims equal the
+    array's, which is what the (8, 128) block rule admits for any BT and
+    strip count; callers view the map as (B, n_strips) outside."""
     if strip_axis == 0:
-        return pl.BlockSpec((bt, 1), lambda i: (0, i))
-    return pl.BlockSpec((bt, 1), lambda b, i: (b, i))
+        return pl.BlockSpec((bt, 1, 1, 1), lambda i: (0, i, 0, 0))
+    return pl.BlockSpec((bt, 1, 1, 1), lambda b, i: (b, i, 0, 0))
 
 
 def skip_specs_operands(
@@ -279,18 +310,27 @@ def skip_specs_operands(
             f"{[(s.shape, s.dtype) for s in shapes]}"
         )
     specs = [strip_map_spec(bt, strip_axis)]
-    operands = [skip_mask.astype(jnp.int32)]
+    operands = [skip_mask.astype(jnp.int32).reshape(b, n, 1, 1)]
     for p, s in zip(prev_out, shapes):
         specs.append(out_strip_spec(bh, s.shape[-1], bt, strip_axis))
         operands.append(p)
     return specs, operands
 
 
+def any_per_image(mask):
+    """(BT, R, C) bool → (BT, 1, 1) int32, 1 where the image's tile has
+    any set element. Lanes reduce first, then sublanes: the TPU kernel
+    compiler rejects the fused two-axis reduction for BT > 1."""
+    m = jnp.max(mask.astype(jnp.int32), axis=-1, keepdims=True)
+    return jnp.max(m, axis=-2, keepdims=True)
+
+
 def write_outputs(out_refs, compute, skip_ref=None, prev_refs=None):
     """Kernel-side output write, masked or plain.
 
-    Without a mask every output ref takes its computed value. With
-    ``skip_ref`` (the (BT, 1) per-image static flags) the temporal
+    Without a mask every output ref takes its computed value, cast to the
+    ref's dtype (kernels compute 8-bit outputs at 32 bits). With
+    ``skip_ref`` (the (BT, 1, 1, 1) per-image static flags) the temporal
     strip-mask contract applies: a fully static (image-block, strip)
     tile never runs ``compute`` (``pl.when`` predication — the stencil
     math is skipped outright) and copies the stored previous outputs; a
@@ -301,11 +341,12 @@ def write_outputs(out_refs, compute, skip_ref=None, prev_refs=None):
     out_refs = tuple(out_refs)
     if skip_ref is None:
         for ref, val in zip(out_refs, compute()):
-            ref[...] = val
+            ref[...] = val.astype(ref.dtype)
         return
     prev_refs = tuple(prev_refs)
-    skip = skip_ref[...] != 0  # (bt, 1)
-    all_skip = jnp.all(skip)
+    flags = skip_ref[...].reshape(skip_ref.shape[0], 1, 1)  # (bt, 1, 1)
+    skip = flags != 0
+    all_skip = jnp.min(flags) != 0
 
     @pl.when(all_skip)
     def _reuse():
@@ -314,9 +355,11 @@ def write_outputs(out_refs, compute, skip_ref=None, prev_refs=None):
 
     @pl.when(~all_skip)
     def _compute():
-        sk = skip.reshape(skip.shape[0], 1, 1)
         for ref, prev, val in zip(out_refs, prev_refs, compute()):
-            ref[...] = jnp.where(sk, prev[...], val)
+            # select at the computed (32-bit) width: 8-bit outputs are an
+            # HBM format only, the chip has no 8-bit vector select
+            old = prev[...].astype(val.dtype)
+            ref[...] = jnp.where(skip, old, val).astype(ref.dtype)
 
 
 def pad_cols(x, halo: int, mode: str):
@@ -380,36 +423,46 @@ def pad_cols_to_multiple(x, m: int):
 
 
 def pack_mask(x):
-    """bool/uint8 mask (..., W) → (..., W//32) uint32, bit k = pixel
-    32·word + k. W must be a multiple of 32 (see pad_cols_to_multiple)."""
+    """bool/uint8 mask (..., W) → (..., NW) uint32 words, NW = W//32,
+    in the PLANAR bit layout: bit k of word j is pixel k·NW + j.
+
+    Each bit plane is one contiguous lane slice of the mask, so packing
+    is 32 shifted ORs, with no lane-splitting reshape. Horizontal
+    neighbours are adjacent WORDS of the same bit plane (see the
+    hysteresis kernel's ``_hshift``). W must be a multiple of 32 (see
+    pad_cols_to_multiple). Runs in XLA: inside a TPU kernel the same
+    code compiles but loses bit planes 16-22 (``fused_canny_strips``).
+    """
     w = x.shape[-1]
     if w % _BITS:
         raise ValueError(f"W={w} not a multiple of {_BITS}")
-    b = (x != 0).reshape(*x.shape[:-1], w // _BITS, _BITS).astype(jnp.uint32)
-    return jnp.sum(b << jnp.arange(_BITS, dtype=jnp.uint32), axis=-1, dtype=jnp.uint32)
+    nw = w // _BITS
+    bits = (x != 0).astype(jnp.int32)
+    words = bits[..., :nw]
+    for k in range(1, _BITS):
+        words = words | (bits[..., k * nw : (k + 1) * nw] << k)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def unpack_mask(words):
-    """(..., NW) uint32 → (..., NW·32) uint8 mask."""
-    bits = (words[..., None] >> jnp.arange(_BITS, dtype=jnp.uint32)) & jnp.uint32(1)
+    """(..., NW) uint32 planar words → (..., NW·32) uint8 mask."""
+    planes = words[..., None, :] >> jnp.arange(_BITS, dtype=jnp.uint32)[:, None]
+    bits = planes & jnp.uint32(1)  # (..., 32, NW): plane k = pixels k·NW + j
     return bits.reshape(*words.shape[:-1], words.shape[-1] * _BITS).astype(jnp.uint8)
 
 
 def select_row(x, idx):
-    """Per-image dynamic row select: (BT, N, W) + (BT, 1, 1) indices →
-    (BT, 1, W). The block batch dim is static, so this unrolls into BT
-    single-row dynamic slices — far cheaper than a one-hot reduction."""
-    rows = [
-        jax.lax.dynamic_slice_in_dim(x[i], idx[i, 0, 0], 1, axis=0)
-        for i in range(x.shape[0])
-    ]
-    return jnp.stack(rows)
+    """Per-image row select: (BT, N, W) + (BT, 1, 1) indices → (BT, 1, W).
+
+    An iota mask and a max-reduction over the rows, which the TPU kernel
+    compiler lowers (it has no dynamic slice); the one selected value
+    survives the max exactly, ±0 and all.
+    """
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[-2], 1), 1)
+    return jnp.max(jnp.where(rows == idx, x, -jnp.inf), axis=-2, keepdims=True)
 
 
 def select_col(x, idx):
-    """Per-image dynamic column select on axis -1 (see ``select_row``)."""
-    cols = [
-        jax.lax.dynamic_slice_in_dim(x[i], idx[i, 0, 0], 1, axis=1)
-        for i in range(x.shape[0])
-    ]
-    return jnp.stack(cols)
+    """Per-image column select on axis -1 (see ``select_row``)."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, x.shape[-1]), 2)
+    return jnp.max(jnp.where(cols == idx, x, -jnp.inf), axis=-1, keepdims=True)

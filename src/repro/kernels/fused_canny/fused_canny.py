@@ -65,20 +65,17 @@ def _kernel(
     # inside a pl.when branch, where program_id cannot be staged
     i = pl.program_id(grid_axis)
     n_strips = pl.num_programs(grid_axis)
-    ht = hw_ref[:, 0].reshape(bt, 1, 1)  # per-image true height
-    wt = hw_ref[:, 1].reshape(bt, 1, 1)  # per-image true width
+    ht, wt = common.true_sizes(hw_ref)  # per-image true (h, w)
     # First GLOBAL row this kernel's array owns: 0 locally; under shard_map
     # the shard's row offset, so all border logic anchored at per-image
     # true sizes keeps working on a shard-local grid.
     row0 = off_ref[0, 0] + i * bh
 
-    n_out = 2 if emit == "packed" else 1
     if masked:
-        skip_ref, *rest = refs
-        prev_out_refs, out_refs = rest[:n_out], rest[n_out:]
+        skip_ref, prev_out_ref, out_ref = refs
     else:
-        out_refs = refs
-        skip_ref = prev_out_refs = None
+        (out_ref,) = refs
+        skip_ref = prev_out_ref = None
 
     def frontend():
         # ---- gaussian on the (bt, bh + 2*h2, w) extended tile -------------
@@ -114,10 +111,9 @@ def _kernel(
         # sobel; virtual rows (g < 0 or g >= ht) and cols (>= wt) were
         # instead blurred from replicated/padded inputs. Overwrite with the
         # first/last TRUE blur row/col. The last true row may live in this
-        # strip at dynamic per-image local index (ht-1) - row0 + 2 — fetched
-        # with one unrolled dynamic slice per in-block image. Rows first,
-        # cols second: the bottom-right corner then lands on
-        # blur[ht-1, wt-1].
+        # strip at dynamic per-image local index (ht-1) - row0 + 2 — picked
+        # out by an iota mask (``common.select_row``). Rows first, cols
+        # second: the bottom-right corner then lands on blur[ht-1, wt-1].
         top_fix = jnp.broadcast_to(blur[..., 2:3, :], blur.shape)
         last_local = jnp.clip(ht - 1 - row0 + 2, 0, nblur - 1)
         bot_row = common.select_row(blur, last_local)
@@ -142,23 +138,20 @@ def _kernel(
 
         if emit == "nms":
             return (suppressed,)
-        if emit == "code":  # fused double threshold, 1 B/px
-            return (
-                (suppressed >= low).astype(jnp.uint8)
-                + (suppressed >= high).astype(jnp.uint8),
-            )
-        # "packed": strong/weak masks bit-packed for hysteresis, 2 bit/px
-        return (
-            common.pack_mask(suppressed >= high),
-            common.pack_mask(suppressed >= low),
-        )
+        # fused double threshold, 1 B/px
+        code = (suppressed >= low).astype(jnp.int32) + (
+            suppressed >= high
+        ).astype(jnp.int32)
+        return (code,)
 
     # Strip-mask path (masked): ``skip_ref`` flags per-image STATIC strips
     # — every input row this strip's stencil reads is bitwise identical to
     # the previous frame, so the stored previous output IS this frame's
     # output (purity; DESIGN.md §9). ``common.write_outputs`` skips the
     # stencil math for fully static tiles via ``pl.when``.
-    common.write_outputs(out_refs, frontend, skip_ref, prev_out_refs)
+    common.write_outputs(
+        (out_ref,), frontend, skip_ref, (prev_out_ref,) if masked else None
+    )
 
 
 def fused_canny_strips(
@@ -181,6 +174,12 @@ def fused_canny_strips(
     """(B, H, W) f32 → NMS magnitudes (f32), threshold code map (uint8),
     or — emit="packed" — the (strong, weak) masks bit-packed 32 px/uint32
     word, ready for the hysteresis kernel (requires W % 32 == 0).
+
+    "packed" runs the kernel's code map and packs it in XLA: packing
+    inside the kernel (lane slices of the mask ORed into words) compiles
+    for a TPU v5e but drops bit planes 16-22 there, so the words are
+    built where they come out right — at the cost of the 1 B/px code map
+    crossing HBM once.
 
     ``true_hw`` is a (B, 2) int32 table of pre-padding (height, width) per
     image: border fixes anchor there, not at the padded grid end. Defaults
@@ -211,6 +210,21 @@ def fused_canny_strips(
         raise ValueError(emit)
     if (skip_mask is None) != (prev_out is None):
         raise ValueError("skip_mask and prev_out come together")
+    if emit == "packed":
+        if imgs.shape[-1] % 32:
+            raise ValueError(
+                f"emit='packed' needs W % 32 == 0, got W={imgs.shape[-1]}"
+            )
+        prev_code = None
+        if prev_out is not None:
+            strong_w, weak_w = prev_out
+            prev_code = common.unpack_mask(strong_w) + common.unpack_mask(weak_w)
+        code = fused_canny_strips(
+            imgs, sigma, radius, low, high, l2_norm, "code", block_rows,
+            interpret, true_hw, batch_block, halos, row_offset, skip_mask,
+            prev_code,
+        )
+        return common.pack_mask(code >= 2), common.pack_mask(code >= 1)
     if interpret is None:
         interpret = common.default_interpret()
     b, h, w = imgs.shape
@@ -236,22 +250,9 @@ def fused_canny_strips(
     taps = tuple(float(t) for t in gaussian_kernel1d(sigma, radius))
     grid, sx = common.strip_grid(b, bt, n)
     prev, cur, nxt = common.strip_specs(n, bh, w, bt, sx)
-    if emit == "packed":
-        if w % 32:
-            raise ValueError(f"emit='packed' needs W % 32 == 0, got W={w}")
-        nw = w // 32
-        out_specs = (
-            common.out_strip_spec(bh, nw, bt, sx),
-            common.out_strip_spec(bh, nw, bt, sx),
-        )
-        out_shape = (
-            jax.ShapeDtypeStruct((b, h, nw), jnp.uint32),
-            jax.ShapeDtypeStruct((b, h, nw), jnp.uint32),
-        )
-    else:
-        out_specs = common.out_strip_spec(bh, w, bt, sx)
-        out_dtype = jnp.float32 if emit == "nms" else jnp.uint8
-        out_shape = jax.ShapeDtypeStruct((b, h, w), out_dtype)
+    out_specs = common.out_strip_spec(bh, w, bt, sx)
+    out_dtype = jnp.float32 if emit == "nms" else jnp.uint8
+    out_shape = jax.ShapeDtypeStruct((b, h, w), out_dtype)
     in_specs = [
         prev,
         cur,
@@ -267,7 +268,7 @@ def fused_canny_strips(
         imgs,
         halo_top.astype(imgs.dtype),
         halo_bot.astype(imgs.dtype),
-        true_hw.astype(jnp.int32),
+        common.per_image_table(true_hw),
         row_offset,
     ]
     if skip_mask is not None:
@@ -293,4 +294,5 @@ def fused_canny_strips(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(*operands)

@@ -24,7 +24,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.canny.hysteresis import warm_seed
 from repro.core.patterns.dist import LOCAL, Dist, StencilCtx
 from repro.core.patterns.stencil import overlap_strips
@@ -41,7 +40,10 @@ def _shard_grid(h: int, dist: Dist, h2: int, block_rows: int | None):
     """Shard-local strip geometry for a global height ``h``: → (padded
     global height, shard-local height, block rows). Row padding must be
     GLOBAL (local pads would land between shards), so the padded height
-    is a multiple of space_size * bh and each shard's rows divide bh."""
+    is a multiple of space_size * bh and each shard's rows divide bh.
+    A shard of several strips needs sublane-aligned strips (the TPU
+    kernel compiler's block rule); when no aligned divisor of the
+    unpadded shard height exists, rows pad to whole 128-row strips."""
     ms = dist.space_size()
     if block_rows is not None:
         bh = block_rows
@@ -50,7 +52,10 @@ def _shard_grid(h: int, dist: Dist, h2: int, block_rows: int | None):
         if hl % bh:
             raise ValueError(f"shard-local height {hl} not a multiple of {bh}")
     else:
-        bh = common.pick_block_rows_divisor(-(-h // ms), min_rows=h2)
+        hl0 = -(-h // ms)
+        bh = common.pick_block_rows_divisor(hl0, min_rows=h2)
+        if bh % common.SUBLANES and bh < hl0:
+            bh = common.pick_block_rows(hl0)
         hp = -(-h // (ms * bh)) * ms * bh
         hl = hp // ms
         bh = common.pick_block_rows_divisor(hl, min_rows=h2)
@@ -108,7 +113,7 @@ def _run_sharded(imgs, true_hw, min_rows, block_rows, dist, shard_fn):
         row_off = jnp.full((1, 1), off, jnp.int32)
         return shard_fn(x, hw, row_off, bh, fctx)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=dist.mesh,
         in_specs=(dist.batch_spec(), dist.table_spec()),
@@ -248,9 +253,9 @@ def fused_canny(
 ) -> jax.Array:
     """Full Canny: fused front-end + in-VMEM-fixpoint hysteresis. uint8 edges.
 
-    When W divides 32 the front-end hands the hysteresis kernel bit-packed
-    strong/weak words directly (2 bit/px between stages, no unpacked mask
-    ever touches HBM); otherwise it falls back to the uint8 code map.
+    When W divides 32 the front-end's code map is packed into strong/weak
+    words (``fused_canny_strips(emit="packed")``) for the packed fixpoint;
+    otherwise it goes through ``hysteresis_from_masks``.
 
     With a non-local ``dist`` the whole detector runs inside ``shard_map``
     (batch over ``dist.batch_axes``, rows over ``dist.space_axis``) and
@@ -439,7 +444,7 @@ def _sharded_fused_warm(
         edges = common.unpack_mask(packed)
         return edges, strong_w, weak_w, packed, launches, dilations
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=dist.mesh,
         in_specs=(dist.batch_spec(),) * 4 + (dist.table_spec(),),
@@ -531,7 +536,7 @@ def _sharded_fused_warm_skip(
             launches, dilations, fe_launches, fe_strips,
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=dist.mesh,
         in_specs=(dist.batch_spec(),) * 5 + (P(), dist.table_spec()),

@@ -160,4 +160,5 @@ def gaussian_blur_strips(
         out_specs=common.out_strip_spec(bh, w, bt, sx),
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(*operands)

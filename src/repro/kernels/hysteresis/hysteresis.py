@@ -4,7 +4,7 @@ The paper's Amdahl-bottleneck stage, made parallel (see
 core/canny/hysteresis.py for the algorithm), then made *bit-parallel*:
 edge/weak masks are packed 32 pixels per uint32 word, so one VPU lane
 propagates 32 columns per op. A masked 8-neighbour dilation becomes a
-3-row OR + word shifts with cross-word carries — ~32× fewer elements
+3-row OR + adjacent-word ORs (planar bit layout) — ~32× fewer elements
 per sweep than the uint8 formulation, and 8× less HBM traffic (1 bit/px
 end-to-end: ops.py packs once, every sweep launch reads/writes words,
 unpack happens once at the end).
@@ -31,13 +31,14 @@ from jax.experimental import pallas as pl
 from repro.kernels import common
 
 def _hshift(v):
-    """OR of v with its left/right pixel neighbours, packed: in-word bit
-    shifts plus the carry bit from the adjacent word."""
-    nw = v.shape[-1]
-    padded = common.pad_cols(v, 1, "zero")
-    pw = padded[..., :nw]  # word to the left
-    xw = padded[..., 2:]  # word to the right
-    return v | (v << 1) | (pw >> 31) | (v >> 1) | (xw << 31)
+    """OR of v with its left/right pixel neighbours in the planar layout
+    (``common.pack_mask``: bit k of word j is pixel k·NW + j). A pixel's
+    neighbours sit in the adjacent WORDS of the same bit plane; only the
+    first/last word wraps to the previous/next plane (a one-bit shift),
+    and the bits shifted in there are the zero border outside the image."""
+    left = jnp.concatenate([v[..., -1:] << 1, v[..., :-1]], axis=-1)
+    right = jnp.concatenate([v[..., 1:], v[..., :1] >> 1], axis=-1)
+    return v | left | right
 
 
 def _kernel(
@@ -70,7 +71,7 @@ def _kernel(
     def body(carry):
         e, _, n = carry
         new = dilate_masked(e)
-        return new, jnp.any(new != e), n + 1
+        return new, jnp.max(common.any_per_image(new != e)) > 0, n + 1
 
     final, _, trips = lax.while_loop(
         lambda c: c[1], body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32))
@@ -81,10 +82,8 @@ def _kernel(
     # masked dilations the tile ran (trips minus the verifying one). The
     # outer loop only tests > 0, so control is unchanged; summed, it is the
     # in-VMEM sweep work a warm start saves.
-    changed = jnp.any(final != init, axis=(-2, -1))
-    changed_ref[...] = jnp.where(changed, trips - 1, 0).astype(jnp.int32).reshape(
-        bt, 1
-    )
+    changed = common.any_per_image(final != init)  # (bt, 1, 1)
+    changed_ref[...] = jnp.where(changed > 0, trips - 1, 0).reshape(bt, 1, 1, 1)
 
 
 def hysteresis_sweep_strips(
@@ -129,7 +128,7 @@ def hysteresis_sweep_strips(
     bt = batch_block or common.pick_batch_block(b, bh, nw)
     grid, sx = common.strip_grid(b, bt, n)
     prev, cur, nxt = common.strip_specs(n, bh, nw, bt, sx)
-    return pl.pallas_call(
+    out, changed = pl.pallas_call(
         functools.partial(_kernel, grid_axis=sx),
         grid=grid,
         in_specs=[
@@ -146,7 +145,9 @@ def hysteresis_sweep_strips(
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, h, nw), jnp.uint32),
-            jax.ShapeDtypeStruct((b, n), jnp.int32),
+            jax.ShapeDtypeStruct((b, n, 1, 1), jnp.int32),
         ),
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(edges, edges, edges, weak, top.astype(jnp.uint32), bot.astype(jnp.uint32))
+    return out, changed.reshape(b, n)

@@ -57,8 +57,7 @@ def _kernel(
     bt, bh, w = cur_ref.shape
     i = pl.program_id(grid_axis)
     n_strips = pl.num_programs(grid_axis)
-    ht = hw_ref[:, 0].reshape(bt, 1, 1)
-    wt = hw_ref[:, 1].reshape(bt, 1, 1)
+    ht, wt = common.true_sizes(hw_ref)  # per-image true (h, w)
     row0 = off_ref[0, 0] + i * bh
 
     # ---- gaussian on the (bt, bh + 2*h2, w) extended tile ------------------
@@ -198,12 +197,13 @@ def log_strips(
         out_specs=common.out_strip_spec(bh, w, bt, sx),
         out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.uint8),
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(
         imgs,
         imgs,
         imgs,
         halo_top.astype(imgs.dtype),
         halo_bot.astype(imgs.dtype),
-        true_hw.astype(jnp.int32),
+        common.per_image_table(true_hw),
         row_offset,
     )

@@ -27,7 +27,9 @@ from repro.kernels import common
 
 
 def nms_math(ext: jax.Array, dirs: jax.Array, bh: int, w: int) -> jax.Array:
-    """ext: zero-padded (..., bh+2, w+2) magnitudes; dirs: (..., bh, w) bins."""
+    """ext: zero-padded (..., bh+2, w+2) magnitudes; dirs: (..., bh, w) bins
+    (uint8 from HBM or int32 from the fused sobel; compared as int32)."""
+    dirs = dirs.astype(jnp.int32)
 
     def at(dy, dx):
         return jax.lax.slice_in_dim(
@@ -152,4 +154,5 @@ def nms_strips(
         out_specs=common.out_strip_spec(bh, w, bt, sx),
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(*operands)

@@ -75,8 +75,7 @@ def _kernel(
 ):
     bt, bh, w = cur_ref.shape
     grid_pos = (pl.program_id(grid_axis), pl.num_programs(grid_axis))
-    ht = hw_ref[:, 0].reshape(bt, 1, 1)
-    wt = hw_ref[:, 1].reshape(bt, 1, 1)
+    ht, wt = common.true_sizes(hw_ref)  # per-image true (h, w)
     row0 = off_ref[0, 0] + grid_pos[0] * bh
     ext = common.assemble_rows(
         prev_ref[...],
@@ -143,12 +142,13 @@ def roberts_strips(
         out_specs=common.out_strip_spec(bh, w, bt, sx),
         out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.uint8),
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(
         imgs,
         imgs,
         imgs,
         halo_top.astype(imgs.dtype),
         halo_bot.astype(imgs.dtype),
-        true_hw.astype(jnp.int32),
+        common.per_image_table(true_hw),
         row_offset,
     )

@@ -45,7 +45,7 @@ def sobel_math(ext: jax.Array, bh: int, w: int, l2_norm: bool, clamp=None):
 
     ``ext`` must already have 1 halo row AND 1 halo col on each side;
     leading dims (the in-block batch) broadcast through. Returns
-    (mag, dirs) of shape (..., bh, w).
+    (mag, dirs) of shape (..., bh, w), dirs as int32 bins.
 
     ``clamp = (grow, ht, gcol, wt)`` anchors the stencil at per-image
     TRUE sizes via the shared ``core.canny.sobel`` clamp rule
@@ -90,7 +90,9 @@ def sobel_math(ext: jax.Array, bh: int, w: int, l2_norm: bool, clamp=None):
     dirs = jnp.where(horiz, 0, jnp.where(vert, 2, jnp.where(same, 1, 3)))
     if clamp is not None:
         mag = zero_outside_true(mag, clamp)
-    return mag.astype(jnp.float32), dirs.astype(jnp.uint8)
+    # int32 bins: the chip's vector unit has no 8-bit arithmetic, so the
+    # uint8 HBM format is only applied when the kernel stores them
+    return mag.astype(jnp.float32), dirs.astype(jnp.int32)
 
 
 def _kernel(
@@ -111,8 +113,7 @@ def _kernel(
         pl.program_id(grid_axis),
         pl.num_programs(grid_axis),
     )
-    ht = hw_ref[:, 0].reshape(bt, 1, 1)
-    wt = hw_ref[:, 1].reshape(bt, 1, 1)
+    ht, wt = common.true_sizes(hw_ref)  # per-image true (h, w)
     row0 = off_ref[0, 0] + grid_pos[0] * bh  # first GLOBAL row of this strip
     if masked:
         skip_ref, prev_mag_ref, prev_dir_ref, mag_ref, dir_ref = refs
@@ -205,7 +206,7 @@ def sobel_strips(
         imgs,
         halo_top.astype(imgs.dtype),
         halo_bot.astype(imgs.dtype),
-        true_hw.astype(jnp.int32),
+        common.per_image_table(true_hw),
         row_offset,
     ]
     if skip_mask is not None:
@@ -226,4 +227,5 @@ def sobel_strips(
         ),
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=common.compiler_params(),
     )(*operands)

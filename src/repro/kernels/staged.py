@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.canny.hysteresis import warm_seed
 from repro.core.patterns.dist import LOCAL, Dist, StencilCtx
 from repro.core.patterns.stencil import overlap_strips
@@ -311,7 +310,7 @@ def _sharded_staged_warm(
         )
         return common.unpack_mask(packed), strong_w, weak_w, packed, launches, dilations
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=dist.mesh,
         in_specs=(dist.batch_spec(),) * 4 + (dist.table_spec(),),
@@ -373,7 +372,7 @@ def _sharded_staged_warm_skip(
             launches, dilations, fe_launches, fe_strips,
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=dist.mesh,
         in_specs=(dist.batch_spec(),) * 9 + (P(), dist.table_spec()),

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core.canny import CannyParams
 from repro.core.canny.golden_circle import compile_plan, plan
+from repro.launch.compile_cache import use_compile_cache
 from repro.data.images import save_pgm, synthetic_batch
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--out-dir", default="canny_out")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     params = CannyParams(sigma=args.sigma, low=args.low, high=args.high)
     p = plan(args.batch, args.height, args.width, params, mesh=None, backend=args.backend)

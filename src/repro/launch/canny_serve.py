@@ -32,7 +32,9 @@ from repro.core.canny import (
     canny_reference,
     registered_ops,
 )
+from repro.core.canny.backends import op_backend
 from repro.data.images import synthetic_image
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import dist_from_spec
 from repro.serve.engine import CannyEngine
 
@@ -134,7 +136,7 @@ def main():
     # operators and serving-capable backends straight from the
     # BackendSpec registry; the engine validates dist capability at
     # construction (fail fast). The backend default resolves AFTER parse
-    # — it depends on --op, and on a no-Pallas host "fused" is not there.
+    # — it depends on --op (``op_backend``).
     serving = [s.name for s in backend_specs() if s.serving_fn]
     ap.add_argument(
         "--op",
@@ -148,8 +150,8 @@ def main():
         "--backend",
         default=None,
         choices=serving,
-        help="serving backend (default: 'fused' for canny when "
-        "registered, else the operator's registered backend)",
+        help="serving backend (default: 'fused' for canny, else the "
+        "operator's registered backend)",
     )
     ap.add_argument("--sigma", type=float, default=1.4)
     ap.add_argument("--low", type=float, default=0.08)
@@ -185,20 +187,15 @@ def main():
         "plane); default: submit back-to-back",
     )
     args = ap.parse_args()
+    use_compile_cache()
 
-    if args.backend is None:
-        candidates = [
-            s.name for s in backend_specs() if s.serving_fn and s.op == args.op
-        ]
-        args.backend = "fused" if "fused" in candidates else candidates[0]
-    else:
-        spec = backend_spec(args.backend)
-        if spec.op != args.op:
-            raise SystemExit(
-                f"backend {args.backend!r} computes operator {spec.op!r}, "
-                f"not {args.op!r} (backends for {args.op!r}: "
-                f"{[s.name for s in backend_specs() if s.op == args.op]})"
-            )
+    try:
+        args.backend = op_backend(args.op, args.backend, cpu_default="fused")
+    except ValueError as e:  # backend/op mismatch
+        raise SystemExit(
+            f"{e} (backends for {args.op!r}: "
+            f"{[s.name for s in backend_specs() if s.op == args.op]})"
+        )
     # every operator verifies against ITS oracle, not canny's
     ref_fn = backend_spec(args.backend).ref_fn or canny_reference
 

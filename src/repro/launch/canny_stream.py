@@ -27,6 +27,8 @@ from repro.core.canny import (
     make_detector,
     registered_ops,
 )
+from repro.core.canny.backends import op_backend
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import dist_from_spec
 from repro.stream import FarmScheduler, Prefetcher, SyntheticStream
 
@@ -107,6 +109,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--verify-every", type=int, default=16, help="0 disables")
     args = ap.parse_args()
+    use_compile_cache()
 
     params = CannyParams(sigma=args.sigma, low=args.low, high=args.high)
     source = SyntheticStream(
@@ -122,14 +125,14 @@ def main():
     if args.skip and args.no_warm:
         raise SystemExit("--skip needs warm-start (drop --no-warm)")
     detector = None
-    ref = canny_reference
-    if args.backend is not None and backend_spec(args.backend).op != args.op:
+    try:
+        args.backend = op_backend(args.op, args.backend, cpu_default="fused")
+    except ValueError as e:  # backend/op mismatch
         raise SystemExit(
-            f"backend {args.backend!r} computes operator "
-            f"{backend_spec(args.backend).op!r}, not {args.op!r} "
-            f"(backends for {args.op!r}: "
+            f"{e} (backends for {args.op!r}: "
             f"{[s.name for s in backend_specs() if s.op == args.op]})"
         )
+    ref = backend_spec(args.backend).ref_fn or canny_reference
     if args.op != "canny":
         # the operator zoo streams COLD: these operators are single-pass
         # stencils with no fixpoint, so there is no temporal state to
@@ -157,12 +160,8 @@ def main():
             detector = make_detector(
                 params, dist, op=args.op, backend=args.backend
             )
-        except ValueError as e:  # backend/op mismatch, unclaimed dist, …
+        except ValueError as e:  # unclaimed dist, …
             raise SystemExit(str(e))
-        name = args.backend or next(
-            s.name for s in backend_specs() if s.op == args.op
-        )
-        ref = backend_spec(name).ref_fn or canny_reference
     if args.engine and pods > 1:
         raise SystemExit(
             "--engine batches frames through one queue and cannot dispatch "
@@ -211,7 +210,7 @@ def main():
     if args.skip and stateful:
         warm_desc += "+skip"
     print(
-        f"stream: op={args.op} {args.frames} frames "
+        f"stream: op={args.op} backend={args.backend} {args.frames} frames "
         f"{args.height}x{args.width} hold={args.hold} "
         f"| {mode} warm={warm_desc}{mesh_desc}",
         flush=True,

@@ -5,17 +5,19 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.patterns.dist import auto_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod meshes: 16×16 = 256 chips single-pod; 2×16×16 multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 4):
     """Small mesh for multi-device CPU tests (8 virtual devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def dist_from_spec(spec: str | None):
@@ -56,14 +58,14 @@ def dist_from_spec(spec: str | None):
     if n == 1:
         return LOCAL
     if pod > 1:
-        mesh = jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+        mesh = auto_mesh((pod, data, model), ("pod", "data", "model"))
         return Dist(
             mesh=mesh,
             batch_axes=("data",) if data > 1 else (),
             space_axis="model" if model > 1 else None,
             pod_axis="pod",
         )
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = auto_mesh((data, model), ("data", "model"))
     return Dist(
         mesh=mesh,
         batch_axes=("data",) if data > 1 else (),

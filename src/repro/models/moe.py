@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec
@@ -359,7 +358,7 @@ def _moe_ffn_ep(p: dict, x: jax.Array, cfg: ModelConfig, capacity_factor: float)
         # differs per data shard — mean over DP makes it truly replicated.
         return out.reshape(bl, sl_, d), _dp_mean(aux)
 
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, w_specs),

@@ -98,7 +98,7 @@ class AotCannyEngine:
     def __init__(
         self,
         params: CannyParams = CannyParams(),
-        backend: str = "fused",
+        backend: str | None = None,
         buckets: Sequence[tuple[int, int]] | None = None,
         calibration: Iterable | None = None,
         lanes: Sequence[int] | None = None,
@@ -109,8 +109,9 @@ class AotCannyEngine:
         dist: Dist = LOCAL,
         name: str = "aot-canny",
     ):
-        from repro.core.canny.backends import backend_spec
+        from repro.core.canny.backends import backend_spec, default_backend
 
+        backend = backend or default_backend("fused")
         spec = backend_spec(backend).require(serving=True, dist=not dist.is_local)
         if dist.pod_axis is not None:
             raise ValueError(

@@ -325,7 +325,7 @@ class CannyEngine:
     def __init__(
         self,
         params: CannyParams = CannyParams(),
-        backend: str = "fused",
+        backend: str | None = None,
         bucket_multiple: int = 64,
         max_batch: int = 8,
         interpret: bool | None = None,
@@ -335,8 +335,9 @@ class CannyEngine:
         max_pending: int | None = None,
         name: str = "canny-engine",
     ):
-        from repro.core.canny.backends import backend_spec
+        from repro.core.canny.backends import backend_spec, default_backend
 
+        backend = backend or default_backend("fused")
         # fail fast, feature named: a backend that cannot serve (or cannot
         # serve under THIS dist) is rejected before any request is queued
         spec = backend_spec(backend).require(

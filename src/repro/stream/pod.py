@@ -59,7 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.canny.params import CannyParams
-from repro.core.patterns.dist import LOCAL, Dist
+from repro.core.patterns.dist import LOCAL, Dist, auto_mesh
 from repro.distributed.fault_tolerance import (
     FaultInjector,
     plan_elastic_mesh,
@@ -372,10 +372,14 @@ class PodWorker:
             )
             self.step = self.temporal.step
         else:
-            from repro.core.canny.backends import UnsupportedFeature, backend_spec
+            from repro.core.canny.backends import (
+                UnsupportedFeature,
+                backend_spec,
+                default_backend,
+            )
             from repro.core.canny.pipeline import make_canny
 
-            name = backend or "fused"
+            name = backend or default_backend("fused")
             if warm and backend_spec(name).supports(
                 dist=True, warm=True, skip=skip
             ):
@@ -465,8 +469,7 @@ def elastic_pod_dist(
     plan = plan_elastic_mesh(per_rank, global_batch, prefer_model=prefer_model)
     data, model = plan.mesh_shape
     used = n_ranks * data * model
-    mesh_devs = np.asarray(devices[:used]).reshape(n_ranks, data, model)
-    mesh = jax.sharding.Mesh(mesh_devs, ("pod", "data", "model"))
+    mesh = auto_mesh((n_ranks, data, model), ("pod", "data", "model"), devices[:used])
     dist = Dist(
         mesh=mesh,
         batch_axes=("data",) if data > 1 else (),
@@ -615,15 +618,6 @@ class ElasticPodFarm:
             self._on_death(rank, exc)
         for rank in self.membership.sweep():
             self._on_swept(rank)
-        # a feeder→death race can land an assignment on a rank that was
-        # declared dead between the owner lookup and the enqueue — sweep
-        # any such straggler back into the orphan pool
-        roster = set(self.membership.roster())
-        with self._lock:
-            for r in [r for r in self._assigned if r not in roster]:
-                if self._assigned[r]:
-                    self._orphans.extend(sorted(self._assigned[r].items()))
-                del self._assigned[r]
         self._redispatch()
         self._maybe_revive()
 
@@ -681,8 +675,11 @@ class ElasticPodFarm:
         for rank, at in list(self._dead_at.items()):
             if self._emitted - at >= self.revive_after:
                 del self._dead_at[rank]
-                self.membership.join(rank, reason="revived")
+                # the new queue exists before the rank can own a seq: a
+                # feeder that finds it on the roster must not enqueue on
+                # the dead incarnation's queue
                 self._spawn(rank, cold=True)  # state rebuilt cold-correct
+                self.membership.join(rank, reason="revived")
                 self.events.append(("join", rank, self._emitted))
 
     # -- stream plane --------------------------------------------------------
@@ -701,10 +698,14 @@ class ElasticPodFarm:
             try:
                 for frame in source:
                     arr = np.asarray(frame, np.float32)
-                    owner = self.membership.owner(seq)
+                    # owner lookup, assignment and enqueue are one step
+                    # under the lock that _reclaim takes: a rank leaving
+                    # the roster meanwhile either never owns the seq or
+                    # has it reclaimed
                     with self._lock:
+                        owner = self.membership.owner(seq)
                         self._assigned.setdefault(owner, {})[seq] = arr
-                    self._queues[owner].put((seq, arr))
+                        self._queues[owner].put((seq, arr))
                     seq += 1
             except BaseException as exc:  # noqa: BLE001
                 with self._lock:

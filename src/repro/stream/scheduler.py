@@ -375,10 +375,14 @@ class FarmScheduler:
             )
             return
         if detector is None and dist is not None and not dist.is_local:
-            from repro.core.canny.backends import UnsupportedFeature, backend_spec
+            from repro.core.canny.backends import (
+                UnsupportedFeature,
+                backend_spec,
+                default_backend,
+            )
             from repro.core.canny.pipeline import make_canny
 
-            name = backend or "fused"
+            name = backend or default_backend("fused")
             if warm and backend_spec(name).supports(
                 dist=True, warm=True, skip=skip
             ):

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.canny.backends import backend_spec
+from repro.core.canny.backends import backend_spec, default_backend
 from repro.core.canny.hysteresis import (
     double_threshold,
     hysteresis_fixpoint_count,
@@ -46,17 +46,6 @@ from repro.core.canny.hysteresis import (
 )
 from repro.core.canny.params import CannyParams
 from repro.core.patterns.dist import LOCAL, Dist, StencilCtx
-
-
-def _resolve_backend(backend: str | None) -> str:
-    if backend is not None:
-        return backend
-    try:
-        import repro.kernels.fused_canny  # noqa: F401
-
-        return "fused"
-    except ImportError:  # pragma: no cover - exercised without Pallas
-        return "jnp"
 
 
 class JnpTemporal:
@@ -207,7 +196,7 @@ class TemporalCanny:
                 "skip=True needs warm=True: the front-end skip reuses the "
                 "threaded per-frame state"
             )
-        self.backend = _resolve_backend(backend)
+        self.backend = backend or default_backend("fused")
         spec = backend_spec(self.backend).require(
             temporal=True, warm=warm, skip=skip
         )

@@ -18,6 +18,7 @@ from repro.core.canny import CannyParams, canny_reference
 from repro.core.canny.pipeline import make_canny
 from repro.data.images import synthetic_image
 from repro.stream import elastic_pod_dist
+from repro.core.patterns.dist import auto_mesh
 import tempfile
 
 
@@ -47,7 +48,7 @@ def main():
     devs = jax.devices()
     assert len(devs) == 8
 
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"), devices=devs)
+    mesh_a = auto_mesh((2, 4), ("data", "model"), devices=devs)
     w = jnp.arange(64.0).reshape(8, 8)
     sh_a = NamedSharding(mesh_a, P("data", "model"))
     w_a = jax.device_put(w, sh_a)
@@ -57,7 +58,7 @@ def main():
         ck.save(3, {"w": w_a}, blocking=True)
 
         # elastic: restore onto a 4-device mesh (half the pod "failed")
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"), devices=devs[:4])
+        mesh_b = auto_mesh((2, 2), ("data", "model"), devices=devs[:4])
         sh_b = NamedSharding(mesh_b, P("data", "model"))
         got, step = ck.restore(
             template={"w": w}, shardings={"w": sh_b}

@@ -13,10 +13,11 @@ from repro.configs.base import ModelConfig
 from repro.models.common import cast_float, init_params
 from repro.models.hints import clear_hints, set_hints
 from repro.models.moe import _moe_ffn_global, moe_ffn, moe_schema
+from repro.core.patterns.dist import auto_mesh
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(
         name="tiny-moe", family="moe", n_layers=1, d_model=16, n_heads=2,
         n_kv_heads=2, d_ff=32, vocab_size=64, n_experts=8, top_k=2, moe_d_ff=24,

@@ -10,12 +10,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.pipeline_parallel import make_pipelined_fn
+from repro.core.patterns.dist import auto_mesh
 
 
 def main():
     devs = jax.devices()
     assert len(devs) >= 4
-    mesh = jax.make_mesh((4,), ("pod",), devices=np.array(devs[:4]))
+    mesh = auto_mesh((4,), ("pod",), devices=np.array(devs[:4]))
 
     # 4 pipeline stages, each an affine map with its own params
     rng = np.random.default_rng(0)

@@ -39,7 +39,7 @@ import numpy as np
 import jax
 
 from repro.core.canny import CannyParams, canny_reference
-from repro.core.patterns.dist import Dist
+from repro.core.patterns.dist import Dist, auto_mesh
 from repro.launch.mesh import dist_from_spec
 from repro.stream import (
     FarmScheduler,
@@ -123,8 +123,8 @@ def single_host_reference() -> list[np.ndarray]:
 def check_inprocess_pod_farm(ref: list[np.ndarray]) -> None:
     """Thread pods over pod-axis meshes: per-rank TemporalCanny (pod x 1)
     and per-rank shard_map sub-meshes (pod x data, pod x model)."""
-    mesh_pd = jax.make_mesh((2, 2), ("pod", "data"))
-    mesh_pm = jax.make_mesh((2, 2), ("pod", "model"))
+    mesh_pd = auto_mesh((2, 2), ("pod", "data"))
+    mesh_pm = auto_mesh((2, 2), ("pod", "model"))
     dists = {
         "podx d": Dist(mesh=mesh_pd, batch_axes=("data",), pod_axis="pod"),
         "podx m": Dist(mesh=mesh_pm, space_axis="model", pod_axis="pod"),

@@ -18,13 +18,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 from repro.core.canny import CannyParams, canny_reference
 from repro.core.canny.golden_circle import plan, compile_plan
 from repro.core.canny.pipeline import make_canny
-from repro.core.patterns.dist import Dist
+from repro.core.patterns.dist import Dist, auto_mesh
 from repro.data.images import synthetic_batch, synthetic_image
+from repro.launch.mesh import dist_from_spec
+from repro.stream import elastic_pod_dist
 from repro.kernels.fused_canny.ops import fused_canny
 from repro.serve.engine import CannyEngine
 
@@ -39,13 +40,13 @@ def check_fused_under_shard_map():
     imgs = synthetic_batch(8, 64, 96, seed=3)
     local = np.asarray(fused_canny(jnp.asarray(imgs), *ARGS))
 
-    mesh_d = jax.make_mesh((8,), ("data",))
+    mesh_d = auto_mesh((8,), ("data",))
     dist_d = Dist(mesh=mesh_d, batch_axes=("data",), space_axis=None)
     got = np.asarray(fused_canny(jnp.asarray(imgs), *ARGS, dist=dist_d))
     assert (got == local).all(), "data-only mesh diverged from local fused"
     print("fused shard_map data-only: OK")
 
-    mesh_dm = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_dm = auto_mesh((2, 4), ("data", "model"))
     dist_dm = Dist(mesh=mesh_dm, batch_axes=("data",), space_axis="model")
     got = np.asarray(fused_canny(jnp.asarray(imgs), *ARGS, dist=dist_dm))
     assert (got == local).all(), "data x model mesh diverged from local fused"
@@ -92,10 +93,32 @@ def check_mesh_engine(dist_d, dist_dm):
     print("make_canny mesh serving: OK")
 
 
+def check_mesh_builders_auto():
+    """Every mesh builder gives ``Auto`` axes: jax 0.9's ``make_mesh``
+    defaults to ``Explicit``, under which the kernels' row crops on a
+    sharded dim are refused."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_host_mesh
+
+    meshes = {
+        "dist_from_spec 2x4": dist_from_spec("2x4").mesh,
+        "dist_from_spec 2x2x2": dist_from_spec("2x2x2").mesh,
+        "pod_slice": dist_from_spec("2x2x2").pod_slice(1).mesh,
+        "make_host_mesh": make_host_mesh(),
+        "elastic_pod_dist": elastic_pod_dist(2)[0].mesh,
+    }
+    for name, m in meshes.items():
+        assert set(m.axis_types) == {AxisType.Auto}, (name, m.axis_types)
+    print("mesh builders: Auto axes OK")
+
+
 def main():
     devs = jax.devices()
     assert len(devs) == 8, devs
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
+
+    check_mesh_builders_auto()
 
     dist_d, dist_dm = check_fused_under_shard_map()
     check_mesh_engine(dist_d, dist_dm)
@@ -143,7 +166,7 @@ def main():
     x = np.arange(32, dtype=np.float32)
     want_scan = np.cumsum(x)
     scan_fn = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda xl: pattern_scan(jnp.add, xl, axis_name="model"),
             mesh=mesh,
             in_specs=P("model"),

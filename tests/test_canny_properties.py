@@ -189,3 +189,6 @@ def test_shard_grid_random_mesh_shapes(h, ms, radius, block_rows):
     assert hp >= h and hp % ms == 0
     assert hl == hp // ms and hl % bh == 0
     assert bh >= h2 or block_rows is not None
+    # several strips per shard only at sublane-aligned heights (the TPU
+    # kernel compiler's block rule)
+    assert bh % 8 == 0 or bh == hl or block_rows is not None

@@ -46,7 +46,7 @@ from repro.core.canny import (
     conformance_cells,
     make_canny,
 )
-from repro.core.patterns.dist import LOCAL, Dist
+from repro.core.patterns.dist import LOCAL, Dist, auto_mesh
 from repro.data.images import synthetic_image
 from repro.stream import TemporalCanny
 
@@ -75,7 +75,7 @@ def _mesh_dist() -> Dist:
     n = len(jax.devices())
     data = 2 if n >= 2 else 1
     model = max(d for d in (1, 2, 4) if data * d <= n)
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = auto_mesh((data, model), ("data", "model"))
     return Dist(mesh=mesh, batch_axes=("data",), space_axis="model")
 
 

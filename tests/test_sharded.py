@@ -29,6 +29,12 @@ def test_fused_kernels_under_shard_map_bit_identical():
     assert "fused shard_map odd height: OK" in out
 
 
+def test_mesh_builders_give_auto_axes():
+    """dist_from_spec, pod slices, the host mesh and the elastic pod plan
+    all build ``AxisType.Auto`` meshes (the shard_map paths need them)."""
+    assert "mesh builders: Auto axes OK" in _sharded_canny_out()
+
+
 def test_mesh_engine_and_serving_registry():
     out = _sharded_canny_out()
     assert "mesh engine mixed sizes: OK" in out

@@ -3,12 +3,11 @@
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed.sharding import (
     Rules,
-    abstract_mesh,
     activation_rules,
     cache_rules,
     cache_rules_dp,
@@ -45,7 +44,7 @@ def test_dp_layout_spreads_over_both_axes():
 
 
 def test_activation_rules_batch_fitting():
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     r = activation_rules(8, mesh)
     assert r.table["batch"] == ("data",)
     r2 = activation_rules(3, mesh)  # indivisible → unsharded
@@ -55,7 +54,7 @@ def test_activation_rules_batch_fitting():
 
 
 def test_cache_rules_seq_takes_leftover_axes():
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     r = cache_rules(1, mesh)  # batch=1: nothing fits
     assert r.table["batch"] is None
     assert "model" in r.table["seq"] and "data" in r.table["seq"]
@@ -65,7 +64,7 @@ def test_cache_rules_seq_takes_leftover_axes():
 
 @pytest.mark.parametrize("arch", ["deepseek-v3-671b", "smollm-135m", "jamba-1.5-large-398b"])
 def test_param_specs_resolve_for_real_schemas(arch):
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     schema = model_schema(get_config(arch).reduced())
     specs = tree_specs(schema, param_rules(zero=3), mesh)
     # every leaf got a PartitionSpec and no axis repeats within a leaf

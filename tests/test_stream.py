@@ -518,13 +518,11 @@ def test_pod_dist_rejected_by_single_detector_layers():
     """A pod-axis Dist describes a FARM of detectors; every layer that
     builds exactly one detector/queue must reject it loudly rather than
     silently replicate work over the pod axis."""
-    import jax as _jax
-
     from repro.core.canny import make_canny
-    from repro.core.patterns.dist import Dist
+    from repro.core.patterns.dist import Dist, auto_mesh
     from repro.serve.engine import CannyEngine
 
-    mesh = _jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = auto_mesh((1, 1), ("pod", "data"))
     pod_dist = Dist(mesh=mesh, batch_axes=("data",), pod_axis="pod")
     with pytest.raises(ValueError, match="pod"):
         make_canny(PARAMS, pod_dist, backend="fused")
@@ -678,6 +676,52 @@ def test_elastic_pod_farm_heartbeat_declares_stalled_rank_dead():
     _, _, reason = farm.membership.history[1]
     assert "heartbeat timeout" in reason
     assert inj.fired and inj.fired[0][0] == "stall"
+
+
+def test_elastic_pod_farm_revival_races_the_feeder():
+    """A frame fed while a dead rank rejoins must reach the new
+    incarnation: the feeder looks the owner up right after the rank is
+    back on the roster, and the frame must land on a queue a live thread
+    reads, never on the dead incarnation's."""
+    import threading as _threading
+
+    from repro.distributed import FaultInjector
+    from repro.stream import ElasticPodFarm
+
+    class Fake:
+        def step(self, x):
+            return np.asarray(x), None
+
+        def reset(self):
+            pass
+
+    farm = ElasticPodFarm(
+        ranks=3, timeout=10.0, revive_after=1,
+        injector=FaultInjector(kill={(1, 0): "first frame"}),
+        make_worker=lambda rank: Fake(),
+    )
+    rejoined, fed = _threading.Event(), _threading.Event()
+    join = farm.membership.join
+
+    def join_then_let_the_feeder_in(rank, reason="joined"):
+        out = join(rank, reason)
+        rejoined.set()
+        fed.wait(5.0)
+        return out
+
+    farm.membership.join = join_then_let_the_feeder_in
+    frames = [np.full((4, 4), i, np.float32) for i in range(6)]
+
+    def source():
+        yield from frames[:4]
+        assert rejoined.wait(10.0), "rank 1 never rejoined"
+        yield frames[4]  # seq 4 → rank 1 under the (0, 1, 2) roster
+        fed.set()
+        yield frames[5]
+
+    got = list(farm.run(source()))
+    assert [int(g[0, 0]) for g in got] == list(range(6))
+    assert [e[0] for e in farm.events] == ["death", "join"]
 
 
 def test_elastic_pod_farm_last_rank_death_raises():
