@@ -18,6 +18,8 @@ from typing import Callable, Iterable, Iterator, Sequence
 
 import jax
 
+from repro.core.spans import span
+
 
 def pipeline_stages(*stages: Callable) -> Callable:
     """Compose stages f1..fn into one fused program (left-to-right)."""
@@ -35,7 +37,9 @@ class PatternPipeline:
 
     ``fn`` is a jitted device function; ``feed`` yields host batches. The
     executor keeps one batch in flight: transfer(i+1) overlaps compute(i).
-    Deterministic: output order == input order (paper claim C4).
+    Deterministic: output order == input order (paper claim C4). Each
+    transfer is a ``canny.put`` span and each call of ``fn`` a
+    ``canny.step`` span (``core/spans.py``).
     """
 
     def __init__(self, fn: Callable, sharding=None):
@@ -43,9 +47,8 @@ class PatternPipeline:
         self.sharding = sharding
 
     def _put(self, batch):
-        if self.sharding is not None:
+        with span("canny.put"):
             return jax.device_put(batch, self.sharding)
-        return jax.device_put(batch)
 
     def run(self, feed: Iterable) -> Iterator:
         it = iter(feed)
@@ -55,7 +58,8 @@ class PatternPipeline:
             return
         while True:
             cur = nxt
-            out = self.fn(cur)  # dispatches async
+            with span("canny.step"):
+                out = self.fn(cur)  # dispatches async
             try:
                 nxt = self._put(next(it))  # overlaps with compute
             except StopIteration:

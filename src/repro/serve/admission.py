@@ -50,6 +50,7 @@ from typing import Callable
 import numpy as np
 
 from repro.core.patterns.farm import put_cancellable
+from repro.core.spans import span
 from repro.distributed.fault_tolerance import FailFast, StreamTimeout, wait_for
 from repro.serve.aot import AotCannyEngine
 from repro.serve.engine import pack_requests
@@ -137,9 +138,11 @@ class ContinuousBatcher:
     ``submit`` → ``SloTicket``; a dispatch thread packs open bucket slots
     (fill-or-linger), a drain thread resolves results from a bounded
     backlog. ``stats`` (a ``stream.scheduler.StreamStats``) accumulates
-    the per-request SLO plane: queue-wait/service/total latency samples,
+    the per-request SLO plane: enqueue→complete latency samples,
     p50/p95/p99, queue-depth + slot-occupancy gauges, and the pass/fail
-    counter against ``slo_ms``.
+    counter against ``slo_ms``. The dispatch thread's phases are the
+    ``canny.wait``, ``canny.pack`` and (in ``run_packed``) ``canny.put``,
+    ``canny.step`` and ``canny.fetch`` spans (``core/spans.py``).
 
     Use as a context manager, or call ``close()``; both flush open slots,
     stop the workers, and re-raise any recorded worker error.
@@ -289,7 +292,8 @@ class ContinuousBatcher:
                     wait = 0.05
                     if next_deadline is not None:
                         wait = min(wait, max(next_deadline - self._clock(), 1e-4))
-                    self._cond.wait(timeout=wait)
+                    with span("canny.wait"):
+                        self._cond.wait(timeout=wait)
                     continue
             (hb, wb), taken = batch
             lane = self.engine.lane_for(len(taken))
@@ -297,9 +301,10 @@ class ContinuousBatcher:
             for t in taken:
                 t.t_dispatch = t_dispatch
             self.stats.record_occupancy(len(taken), lane)
-            packed, true_hw = pack_requests(
-                [self._images[id(t)] for t in taken], hb, wb, bb=lane
-            )
+            with span("canny.pack"):
+                packed, true_hw = pack_requests(
+                    [self._images[id(t)] for t in taken], hb, wb, bb=lane
+                )
             out = self.engine.run_packed(packed, true_hw)  # blocks on device
             # bounded backlog: a slow drainer (or consumer) throttles the
             # NEXT launch instead of results buffering without limit
@@ -321,12 +326,7 @@ class ContinuousBatcher:
                 for slot, ticket in enumerate(taken):
                     h, w = ticket.shape
                     ticket.t_complete = t_complete
-                    total_ms = (t_complete - ticket.t_enqueue) * 1e3
-                    self.stats.record_request(
-                        (ticket.t_dispatch - ticket.t_enqueue) * 1e3,
-                        (t_complete - ticket.t_dispatch) * 1e3,
-                        total_ms,
-                    )
+                    self.stats.record_request((t_complete - ticket.t_enqueue) * 1e3)
                     self.engine.stats.true_px += h * w
                     ticket._resolve(out[slot, :h, :w])
                     del self._images[id(ticket)]

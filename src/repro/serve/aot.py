@@ -40,6 +40,7 @@ import numpy as np
 from repro.core.canny.backends import UnsupportedFeature
 from repro.core.canny.params import CannyParams
 from repro.core.patterns.dist import LOCAL, Dist
+from repro.core.spans import span
 from repro.serve.engine import (
     EngineStats,
     bucket_batch,
@@ -225,15 +226,24 @@ class AotCannyEngine:
             ) from None
         t0 = time.perf_counter()
         if self._mesh_lock is not None:
-            with self._mesh_lock:  # np.asarray blocks before release
-                out = np.asarray(exe(jnp.asarray(batch), jnp.asarray(true_hw)))
+            with self._mesh_lock:  # the fetch blocks before release
+                out = self._launch(exe, batch, true_hw)
         else:
-            out = np.asarray(exe(jnp.asarray(batch), jnp.asarray(true_hw)))
+            out = self._launch(exe, batch, true_hw)
         dt_ms = (time.perf_counter() - t0) * 1e3
         self.stats.batches += 1
         self.stats.padded_px += lane * hb * wb
         self.stats.latencies_ms.append(dt_ms)
         return out
+
+    @staticmethod
+    def _launch(exe, batch: np.ndarray, true_hw: np.ndarray) -> np.ndarray:
+        with span("canny.put"):
+            args = jnp.asarray(batch), jnp.asarray(true_hw)
+        with span("canny.step"):
+            out = exe(*args)
+        with span("canny.fetch"):
+            return np.asarray(out)  # blocks until the device result lands
 
     def process(self, images: Sequence[np.ndarray]) -> list[np.ndarray]:
         """Synchronous wave over mixed-size requests — same grouping and

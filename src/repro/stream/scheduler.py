@@ -18,9 +18,10 @@ pins.
 flow through ``CannyEngine.submit``/``drain`` waves (mixed sizes OK),
 trading per-frame latency for batch-grid throughput.
 
-``StreamStats`` aggregates fps, per-stage latency (host prep+H2D vs
-device compute), farm queue depths, and the warm-start fixpoint savings
-(sweep launches + in-VMEM dilations, cumulative).
+``StreamStats`` aggregates fps, per-stage latency (the durations of the
+workers' ``canny.prep`` and ``canny.fetch`` spans), farm queue depths,
+and the warm-start fixpoint savings (sweep launches + in-VMEM dilations,
+cumulative).
 """
 
 from __future__ import annotations
@@ -37,6 +38,7 @@ import numpy as np
 from repro.core.canny.params import CannyParams
 from repro.core.patterns.farm import Farm
 from repro.core.patterns.pipeline import PatternPipeline
+from repro.core.spans import span
 from repro.distributed.fault_tolerance import FaultInjector, StepWatchdog
 from repro.serve.engine import percentile
 from repro.stream.temporal import TemporalCanny
@@ -68,15 +70,9 @@ class StreamStats:
         default_factory=collections.Counter
     )
     # continuous-serving SLO plane (serve/admission.py): per-request
-    # latency split (enqueue→dispatch, dispatch→complete, total), the
-    # slot-occupancy gauge (requests packed / lane size per dispatch),
-    # and the pass/fail counter against the slo_ms bound
-    queue_wait_ms: collections.deque = dataclasses.field(
-        default_factory=lambda: collections.deque(maxlen=4096)
-    )
-    service_ms: collections.deque = dataclasses.field(
-        default_factory=lambda: collections.deque(maxlen=4096)
-    )
+    # enqueue→complete latency, the slot-occupancy gauge (requests packed
+    # / lane size per dispatch), and the pass/fail counter against the
+    # slo_ms bound
     request_ms: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=4096)
     )
@@ -130,14 +126,10 @@ class StreamStats:
         with self._lock:
             self.batch_sizes[size] += 1
 
-    def record_request(
-        self, queue_wait_ms: float, service_ms: float, total_ms: float
-    ) -> None:
-        """One continuously-served request's latency split; scores the
-        total against ``slo_ms`` when a bound is set."""
+    def record_request(self, total_ms: float) -> None:
+        """One continuously-served request's enqueue→complete latency;
+        scored against ``slo_ms`` when a bound is set."""
         with self._lock:
-            self.queue_wait_ms.append(queue_wait_ms)
-            self.service_ms.append(service_ms)
             self.request_ms.append(total_ms)
             if self.slo_ms is not None:
                 if total_ms <= self.slo_ms:
@@ -235,6 +227,12 @@ class StreamWorker:
     for stateless detectors). The inner ``PatternPipeline`` keeps one
     frame's transfer in flight while the previous frame computes.
 
+    Each frame passes through five host spans (``core/spans.py``):
+    ``canny.prep`` (the float32 copy, into ``StreamStats.prep_ms``),
+    ``canny.put`` and ``canny.step`` (in the pipeline), ``canny.fetch``
+    (the blocking edge fetch, into ``compute_ms`` and the watchdog) and
+    ``canny.cost_sync`` (the cost scalars read back to the host).
+
     ``rank``/``injector`` are the fault-injection hook: the injector's
     schedule is consulted before every frame this worker computes, so a
     planted kill surfaces exactly like a real worker death (and the
@@ -264,21 +262,23 @@ class StreamWorker:
         out = self.step(x)
         return out if isinstance(out, tuple) else (out, None)
 
+    def _record_fetch(self, ms: float) -> None:
+        self.stats.record_compute(ms, self.name)
+
     def stream(self, frames: Iterable[np.ndarray]) -> Iterator[np.ndarray]:
-        def prepped():  # prep timed here: the pipeline runs it one frame ahead
+        def prepped():  # prep spanned here: the pipeline runs it one frame ahead
             for f in frames:
-                t0 = time.perf_counter()
-                arr = np.asarray(f, np.float32)
-                self.stats.record_prep((time.perf_counter() - t0) * 1e3)
+                with span("canny.prep", self.stats.record_prep):
+                    arr = np.asarray(f, np.float32)
                 yield arr
 
         pipe = PatternPipeline(self._run_step, sharding=self.device)
         for edges, cost in pipe.run(prepped()):
-            t1 = time.perf_counter()
-            out = np.asarray(edges)  # blocks until the device result lands
-            self.stats.record_compute((time.perf_counter() - t1) * 1e3, self.name)
+            with span("canny.fetch", self._record_fetch):
+                out = np.asarray(edges)  # blocks until the device result lands
             if cost is not None:
-                self.stats.record_cost(*(int(c) for c in cost))
+                with span("canny.cost_sync"):  # one device sync per cost scalar
+                    self.stats.record_cost(*(int(c) for c in cost))
             yield out
 
 

@@ -303,8 +303,8 @@ def test_stream_stats_slo_scoreboard():
     from repro.stream.scheduler import StreamStats
 
     stats = StreamStats(slo_ms=10.0)
-    stats.record_request(1.0, 2.0, 3.0)    # pass
-    stats.record_request(5.0, 20.0, 25.0)  # fail
+    stats.record_request(3.0)   # pass
+    stats.record_request(25.0)  # fail
     stats.record_occupancy(2, 4)
     assert stats.slo() == {
         "slo_ms": 10.0, "pass": 1, "fail": 1, "attainment": 0.5,
