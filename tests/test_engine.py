@@ -17,6 +17,7 @@ from repro.serve.engine import (
     CannyEngine,
     bucket_batch,
     next_pow2,
+    pack_requests,
     round_up,
 )
 
@@ -57,6 +58,61 @@ def test_bucket_batch_always_divisible_by_lane(n, lane, want):
 def test_bucket_batch_rejects_negative():
     with pytest.raises(ValueError):
         bucket_batch(-1)
+
+
+# ---------------- request packing -------------------------------------------
+def _pack_oracle(images, hb, wb, bb):
+    """The plain packing: edge-pad each page with np.pad into a zero batch."""
+    batch = np.zeros((bb, hb, wb), np.float32)
+    for slot, img in enumerate(images):
+        h, w = img.shape
+        batch[slot] = np.pad(
+            img.astype(np.float32), ((0, hb - h), (0, wb - w)), mode="edge"
+        )
+    return batch
+
+
+def _page(rng, shape, dtype):
+    if dtype == np.uint8:
+        return rng.integers(0, 256, shape, dtype=np.uint8)
+    return rng.random(shape).astype(dtype)
+
+
+@pytest.mark.parametrize(
+    "shapes,dtype,hb,wb,bb,lane",
+    [
+        ([(5, 7)], np.float32, 8, 12, 1, 1),           # h < hb and w < wb
+        ([(8, 7)], np.float32, 8, 12, 1, 1),           # h == hb
+        ([(5, 12)], np.float32, 8, 12, 1, 1),          # w == wb
+        ([(8, 12)], np.float32, 8, 12, 1, 1),          # both equal
+        ([(1, 1)], np.float32, 4, 6, 1, 1),            # one pixel
+        ([(5, 7), (8, 3)], np.uint8, 8, 12, 2, 1),
+        ([(5, 7), (8, 3)], np.float64, 8, 12, 2, 1),
+        ([(3, 4), (6, 9), (2, 2)], np.float32, 8, 12, 4, 1),  # phantom slot
+        ([(3, 4), (6, 9)], np.float32, 8, 12, None, 3),        # bb from lane
+    ],
+)
+def test_pack_requests_matches_np_pad(shapes, dtype, hb, wb, bb, lane):
+    rng = np.random.default_rng(len(shapes) * 100 + hb)
+    images = [_page(rng, s, dtype) for s in shapes]
+    before = [img.copy() for img in images]
+    batch, true_hw = pack_requests(images, hb, wb, bb=bb, lane=lane)
+    want_bb = bucket_batch(len(images), lane) if bb is None else bb
+    assert batch.dtype == np.float32 and batch.shape == (want_bb, hb, wb)
+    np.testing.assert_array_equal(batch, _pack_oracle(images, hb, wb, want_bb))
+    assert not batch[len(images):].any()
+    want_hw = np.full((want_bb, 2), (hb, wb), np.int32)
+    want_hw[: len(shapes)] = shapes
+    np.testing.assert_array_equal(true_hw, want_hw)
+    for img, was in zip(images, before):
+        np.testing.assert_array_equal(img, was)
+        assert not np.shares_memory(batch, img)
+
+
+def test_pack_requests_rejects_too_many_requests():
+    images = [np.zeros((4, 4), np.float32)] * 3
+    with pytest.raises(ValueError, match="exceed batch bucket"):
+        pack_requests(images, 4, 4, bb=2)
 
 
 # ---------------- backend registry ------------------------------------------
