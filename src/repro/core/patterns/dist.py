@@ -10,6 +10,7 @@ patterns ("parallelism on any underlying parallel architecture").
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Sequence
@@ -79,6 +80,16 @@ class Dist:
             return 1
         return self.mesh.shape[self.pod_axis]
 
+    def pod_devices(self, rank: int) -> np.ndarray:
+        """Pod ``rank``'s devices, laid out as its sub-mesh."""
+        if self.mesh is None or self.pod_axis is None:
+            raise ValueError("pod_slice needs a Dist with a mesh and a pod axis")
+        n = self.pod_size()
+        if not 0 <= rank < n:
+            raise ValueError(f"pod rank {rank} out of range for {n} pods")
+        names = list(self.mesh.axis_names)
+        return np.take(self.mesh.devices, rank, axis=names.index(self.pod_axis))
+
     def pod_slice(self, rank: int) -> "Dist":
         """The per-pod sub-``Dist``: pod ``rank``'s devices, pod axis gone.
 
@@ -88,14 +99,8 @@ class Dist:
         one plain single-device detector per rank while ``2x2x4`` gives
         every rank its own data×model shard_map detector.
         """
-        if self.mesh is None or self.pod_axis is None:
-            raise ValueError("pod_slice needs a Dist with a mesh and a pod axis")
-        n = self.pod_size()
-        if not 0 <= rank < n:
-            raise ValueError(f"pod rank {rank} out of range for {n} pods")
-        names = list(self.mesh.axis_names)
-        devs = np.take(self.mesh.devices, rank, axis=names.index(self.pod_axis))
-        rest = tuple(a for a in names if a != self.pod_axis)
+        devs = self.pod_devices(rank)
+        rest = tuple(a for a in self.mesh.axis_names if a != self.pod_axis)
         if devs.size == 1:
             return Dist()
         sub = auto_mesh(devs.shape, rest, devs)
@@ -126,6 +131,17 @@ class Dist:
 
 
 LOCAL = Dist()
+
+
+def on_device_of(x):
+    """A context under which new arrays are made where ``x`` lives: on
+    the device of an array committed to one device, else on JAX's
+    default device. State made for a frame on chip k is then made on
+    chip k, with no module on, and no copy from, the default device."""
+    devices = x.devices() if getattr(x, "committed", False) else ()
+    if len(devices) == 1:
+        return jax.default_device(next(iter(devices)))
+    return contextlib.nullcontext()
 
 
 class StencilCtx:

@@ -8,14 +8,26 @@ in input order. This module is the host-side scheduler for that shape:
     frame→worker assignment is a pure function of the sequence number
     (deterministic replay, and per-worker streams are contiguous strides
     — worker k sees frames k, k+N, k+2N, … which keeps any per-worker
-    temporal state maximally fresh).
+    temporal state maximally fresh). A ``route`` of the item replaces
+    ``seq % N`` (dispatch by key: every frame of one camera to the
+    worker that holds its session); each routed dispatch, the route
+    choice, the wait for room and the enqueue, is one ``canny.route``
+    span on the feeder thread.
   * **backpressure**: the feeder blocks when a worker's queue is full, so
     at most ``n_workers · (queue_depth + 1)`` items are in flight and a
     slow consumer throttles the source instead of buffering the stream.
+    Under a ``route`` the feeder also waits while ``n_workers ·
+    (queue_depth + 2)`` items are fed but not yet emitted: round-robin
+    spreads every stretch of the feed over all workers, a route need not.
   * **in-order emission**: results park in a reorder buffer keyed by
     sequence number; the consumer sees exactly the input order (paper
     claim C4). The buffer is bounded by the same backpressure invariant:
-    ``|reorder| ≤ n_workers · (queue_depth + 2)``.
+    ``|reorder| ≤ n_workers · (queue_depth + 2)``, with or without a
+    route. A ``.stream`` worker is handed a ``WorkerFeed``; one that
+    holds a finished result until its next item (``PatternPipeline``)
+    hands it back first whenever ``ready()`` says no next item is queued,
+    so no result waits on a later arrival and the routed window always
+    drains.
   * **worker restarts** (``max_restarts > 0``): a worker that raises is
     REPLACED instead of tearing the stream down — its in-flight frames
     (dispatched but unresulted) are re-fed to the replacement first, so
@@ -44,6 +56,7 @@ import threading
 import time
 from typing import Callable, Iterable, Iterator, Sequence
 
+from repro.core.spans import span
 from repro.distributed.fault_tolerance import Backoff, FailFast, StreamTimeout
 
 
@@ -58,6 +71,28 @@ def put_cancellable(q: queue.Queue, msg, cancelled: Callable[[], bool]) -> bool:
         except queue.Full:
             continue
     return False
+
+
+class WorkerFeed:
+    """One worker's items, as a ``.stream`` worker receives them.
+
+    ``ready()`` says, without blocking, whether the next item (or the end
+    of the stream) is already queued. A worker that holds a finished
+    result until it has its next item must hand the result back first
+    when ``ready()`` is False: that item may be long in coming (a camera
+    that pauses), and the results of every other worker wait in order
+    behind this one.
+    """
+
+    def __init__(self, items: Iterator, ready: Callable[[], bool]):
+        self._items = items
+        self.ready = ready
+
+    def __iter__(self) -> "WorkerFeed":
+        return self
+
+    def __next__(self):
+        return next(self._items)
 
 
 class Farm:
@@ -100,13 +135,26 @@ class Farm:
         """Instantaneous input-queue depths (approximate, for stats)."""
         return [q.qsize() for q in self.queues]
 
-    def run(self, feed: Iterable) -> Iterator:
-        """Yield one result per feed item, in feed order."""
+    def run(
+        self,
+        feed: Iterable,
+        route: Callable[[object], int] | None = None,
+        route_sink: Callable[[float], object] | None = None,
+    ) -> Iterator:
+        """Yield one result per feed item, in feed order.
+
+        ``route(item)`` names the worker of each item (default ``seq %
+        n``); ``route_sink`` takes each ``canny.route`` span's ms."""
         n = len(self.workers)
         self.queues = qs = [queue.Queue(maxsize=self.queue_depth) for _ in range(n)]
         reorder: dict[int, object] = {}
         cond = threading.Condition()
-        state = {"total": None, "error": None, "cancel": False}
+        state = {"total": None, "error": None, "cancel": False, "emitted": 0}
+        # routed: most items fed but not yet emitted (module docstring)
+        window = n * (self.queue_depth + 2)
+
+        def room(seq: int) -> bool:
+            return state["cancel"] or seq - state["emitted"] < window
 
         def post_error(exc: BaseException) -> None:
             with cond:
@@ -121,8 +169,16 @@ class Farm:
             seq = 0
             try:
                 for item in feed:
-                    if not put_cancellable(qs[seq % n], (seq, item), cancelled):
-                        return
+                    if route is None:
+                        if not put_cancellable(qs[seq % n], (seq, item), cancelled):
+                            return
+                    else:
+                        with span("canny.route", route_sink):
+                            k = route(item)
+                            with cond:
+                                cond.wait_for(lambda: room(seq))
+                            if not put_cancellable(qs[k], (seq, item), cancelled):
+                                return
                     seq += 1
             except BaseException as exc:  # noqa: BLE001 — relayed to consumer
                 post_error(exc)
@@ -139,11 +195,13 @@ class Farm:
             # every frame pulled but not yet resulted — what a restart
             # must requeue so no sequence number is lost with the worker
             pending: collections.deque[tuple[int, object]] = collections.deque()
+            left = collections.deque(preload)  # a dead predecessor's in-flight frames
 
             def items() -> Iterator:
-                for msg in preload:  # a dead predecessor's in-flight frames
+                while left:
                     if state["cancel"]:
                         return
+                    msg = left.popleft()
                     pending.append(msg)
                     yield msg[1]
                 while True:
@@ -163,8 +221,9 @@ class Farm:
                     pending.append(msg)
                     yield msg[1]
 
+            feed = WorkerFeed(items(), lambda: bool(left) or not qs[k].empty())
             stream = getattr(w, "stream", None)
-            results = stream(items()) if stream is not None else map(w, items())
+            results = stream(feed) if stream is not None else map(w, feed)
             try:
                 for res in results:
                     with cond:
@@ -249,12 +308,15 @@ class Farm:
                     if nxt not in reorder:  # nxt == total: stream exhausted
                         return
                     res = reorder.pop(nxt)
+                    state["emitted"] = nxt + 1
+                    cond.notify_all()  # room for the routed feeder
                 yield res  # outside the lock: the consumer may be slow
                 nxt += 1
         finally:
             with cond:
                 state["cancel"] = True
                 snapshot = list(threads)
+                cond.notify_all()  # the routed feeder may wait for room
             for q in qs:  # unblock workers parked on q.get()
                 try:
                     q.put_nowait(None)

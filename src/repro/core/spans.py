@@ -13,6 +13,22 @@ The program's spans are named ``canny.<phase>``, each covering one host
 phase and never enclosing another, so a trace's spans of one name add up
 to that phase's time on its thread. They sit on the host side only: never
 inside a jitted or Pallas function, and never adding a device sync.
+
+The spans and the counters their sinks feed:
+
+- ``canny.wait``, ``canny.pack``: the serving dispatch thread
+  (``serve/admission.py``), no sink;
+- ``canny.put``, ``canny.step``, ``canny.fetch``: the serving dispatch
+  thread (``serve/aot.py``), no sink; and the stream workers
+  (``stream/scheduler.py:StreamWorker``), with ``canny.prep`` and
+  ``canny.cost_sync``: each adds its ms to ``StreamStats.worker_ms[name]``;
+  ``canny.prep`` also feeds ``prep_ms``, and ``canny.fetch``
+  ``compute_ms``, the watchdog and ``frames_by_device``;
+- ``canny.route``: the farm's feeder in session mode
+  (``core/patterns/farm.py``), the route choice, the wait for room and
+  the enqueue, into
+  ``StreamStats.route_ms``. ``StreamStats.sessions_opened`` counts the
+  sessions ``SessionTable`` opens.
 """
 
 from __future__ import annotations

@@ -19,12 +19,14 @@ included — is declared, not special-cased.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.canny.backends import BackendSpec, register_backend_spec
 from repro.core.canny.params import CannyParams
-from repro.core.patterns.dist import LOCAL, Dist, StencilCtx
+from repro.core.patterns.dist import LOCAL, Dist, StencilCtx, on_device_of
 from repro.kernels import common
 from repro.kernels.gaussian.ops import gaussian_blur
 from repro.kernels.sobel.ops import sobel
@@ -201,9 +203,12 @@ class PackedTemporal:
     ``true_hw``). ``warm=False`` keeps the zero state so every frame runs
     the cold seed — the answer must not change, only the cost counters.
 
-    The hot loop is host-free: the skip gate (``have_prev``) is a device
-    scalar transferred once per reset, the skip DECISION is a traced
-    ``lax.cond`` inside the step program, and in warm mode the threaded
+    The hot loop is host-free: the state, the skip gate (``have_prev``)
+    and the true-size table are made once per reset, on the device of the
+    frame that opens the stream (or with the mesh's sharding), so a
+    stream pinned to chip k never touches the default device; the skip
+    DECISION is a traced ``lax.cond`` inside the step program, and in
+    warm mode the threaded
     state buffers (packed words, stored frame, front-end outputs) are
     DONATED to the step — on donation-capable platforms (TPU/GPU; the
     default gate) each stream updates its state in place instead of
@@ -249,7 +254,6 @@ class PackedTemporal:
             donate = jax.devices()[0].platform in ("tpu", "gpu")
         self.donate = bool(donate)
         self._steps: dict = {}
-        self._have_true = None
         self.reset()
 
     def reset(self) -> None:
@@ -257,6 +261,8 @@ class PackedTemporal:
         self._fe = None
         self._prev_frame = None
         self._have_prev = None
+        self._have_true = None
+        self._true_hw = None
 
     def _step_fn(self, bh: int):
         """One jitted step per (skip, block geometry), resolved through
@@ -312,47 +318,51 @@ class PackedTemporal:
             # zero pad frames: static after frame 0 (no sweeps, no strips,
             # consensus counters unaffected), cropped from the edges below
             x = jnp.pad(x, ((0, bp - b), (0, 0), (0, 0)))
-        true_hw = jnp.broadcast_to(jnp.asarray([h, w], jnp.int32), (bp, 2))
         if self._state is None:
             # three DISTINCT zero buffers: donation rejects the same buffer
-            # appearing under two donated arguments. Under a mesh the
-            # initial state is placed with the SAME NamedSharding the
-            # step's out_specs produce — otherwise frame 0 (default-
-            # sharded zeros) and frame 1 (sharded step outputs) present
-            # different input shardings and jit silently compiles the
-            # whole step twice
+            # appearing under two donated arguments. Locally the state is
+            # made on the frame's own device (a session on chip k keeps
+            # it there). Under a mesh it is placed with the SAME
+            # NamedSharding the step's out_specs produce — otherwise frame
+            # 0 (default-sharded zeros) and frame 1 (sharded step outputs)
+            # present different input shardings and jit silently compiles
+            # the whole step twice
             if self.dist.is_local:
-                shard = lambda v: v  # noqa: E731
+                where, shard = on_device_of(x), lambda v: v
             else:
                 sharding = jax.sharding.NamedSharding(
                     self.dist.mesh, self.dist.batch_spec()
                 )
+                where = contextlib.nullcontext()
                 shard = lambda v: jax.device_put(v, sharding)  # noqa: E731
-            self._state = tuple(
-                shard(jnp.zeros((bp, hp, wp // 32), jnp.uint32))
-                for _ in range(3)
-            )
-            self._prev_frame = shard(jnp.zeros((bp, hp, wp), jnp.float32))
-            self._fe = jax.tree_util.tree_map(
-                shard, self._zero_fe(bp, hp, wp)
-            )
-        if self._have_prev is None:
-            # device-resident gate: one transfer per reset, none per frame
-            self._have_prev = jnp.zeros((), bool)
-            if self._have_true is None:
+            with where:
+                self._state = tuple(
+                    shard(jnp.zeros((bp, hp, wp // 32), jnp.uint32))
+                    for _ in range(3)
+                )
+                self._prev_frame = shard(jnp.zeros((bp, hp, wp), jnp.float32))
+                self._fe = jax.tree_util.tree_map(
+                    shard, self._zero_fe(bp, hp, wp)
+                )
+                # device-resident gate and true-size table: made once per
+                # reset, no transfer and no eager op per frame
+                self._have_prev = jnp.zeros((), bool)
                 self._have_true = jnp.ones((), bool)
+                self._true_hw = jnp.broadcast_to(
+                    jnp.asarray([h, w], jnp.int32), (bp, 2)
+                )
         step_fn = self._step_fn(bh)
         if self.skip:
             edges, fe, state, frame, cost = step_fn(
                 x, self._prev_frame, self._fe, *self._state,
-                self._have_prev, true_hw,
+                self._have_prev, self._true_hw,
             )
             if self.warm:
                 self._fe = fe
                 self._prev_frame = frame
                 self._have_prev = self._have_true
         else:
-            edges, state, cost = step_fn(x, *self._state, true_hw)
+            edges, state, cost = step_fn(x, *self._state, self._true_hw)
         if self.warm:
             self._state = tuple(state)
         edges = edges[..., :w]

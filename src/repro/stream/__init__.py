@@ -26,10 +26,11 @@ from repro.stream.pod import (
     pod_workers,
     reassemble,
     reassemble_elastic,
+    session_route,
     strided,
 )
 from repro.stream.temporal import TemporalCanny
-from repro.stream.scheduler import FarmScheduler, StreamStats, StreamWorker
+from repro.stream.scheduler import FarmScheduler, SessionTable, StreamStats, StreamWorker
 
 __all__ = [
     "CorpusReplay",
@@ -46,9 +47,11 @@ __all__ = [
     "pod_workers",
     "reassemble",
     "reassemble_elastic",
+    "session_route",
     "strided",
     "TemporalCanny",
     "FarmScheduler",
+    "SessionTable",
     "StreamStats",
     "StreamWorker",
 ]

@@ -105,6 +105,18 @@ def owns(seq: int, roster: Sequence[int]) -> int:
     return roster[seq % len(roster)]
 
 
+def session_route(camera: int, roster: Sequence[int], per_chip: int) -> int:
+    """The worker that holds camera ``camera``'s session: ``owns(camera,
+    roster)`` picks its chip, and its rank among that chip's cameras one
+    of the chip's ``per_chip`` workers, worker ``slot * len(roster) +
+    chip's place in the roster`` (worker k serves chip ``k % len(roster)``).
+    Pure in (camera, roster): the same camera always reaches the same
+    worker, whatever the arrival order."""
+    chip = owns(camera, roster)
+    slot = (camera // len(roster)) % per_chip
+    return slot * len(roster) + roster.index(chip)
+
+
 def strided(source: Iterable, pod: PodCtx) -> Iterator[tuple[int, np.ndarray]]:
     """Pod ``rank``'s slice of a frame stream, tagged with the global seq.
 

@@ -18,9 +18,19 @@ pins.
 flow through ``CannyEngine.submit``/``drain`` waves (mixed sizes OK),
 trading per-frame latency for batch-grid throughput.
 
+``FarmScheduler.run_sessions`` is the session mode: a stream of
+``(camera, frame)`` pairs from many cameras, each camera routed by
+``stream/pod.py:session_route`` to one worker pinned to one local chip,
+whose ``SessionTable`` keeps that camera's own ``TemporalCanny``. Every
+camera's frames then meet their own previous frame, so warm start and
+the skip work as they do for one camera, on as many chips as the host
+has.
+
 ``StreamStats`` aggregates fps, per-stage latency (the durations of the
-workers' ``canny.prep`` and ``canny.fetch`` spans), farm queue depths,
-and the warm-start fixpoint savings (sweep launches + in-VMEM dilations,
+workers' ``canny.prep`` and ``canny.fetch`` spans), the five worker
+spans' summed time (``worker_ms``), the feeder's ``canny.route`` time,
+frames per device, sessions opened, farm queue depths, and the
+warm-start fixpoint savings (sweep launches + in-VMEM dilations,
 cumulative).
 """
 
@@ -28,6 +38,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 import time
 from typing import Callable, Iterable, Iterator, Sequence
@@ -41,7 +52,11 @@ from repro.core.patterns.pipeline import PatternPipeline
 from repro.core.spans import span
 from repro.distributed.fault_tolerance import FaultInjector, StepWatchdog
 from repro.serve.engine import percentile
+from repro.stream.pod import session_route
 from repro.stream.temporal import TemporalCanny
+
+# workers a chip: the one-chip farm's default, and session mode's on every chip
+WORKERS_PER_CHIP = 2
 
 
 @dataclasses.dataclass
@@ -91,15 +106,47 @@ class StreamStats:
         default_factory=collections.Counter
     )
     watchdog: StepWatchdog | None = None
+    # the workers' five spans (canny.prep/put/step/fetch/cost_sync):
+    # summed ms by span name, cumulative
+    worker_ms: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter
+    )
+    # session mode: the feeder's canny.route spans (route choice, wait
+    # for room in the farm's window or a full worker queue, enqueue),
+    # summed ms
+    route_ms: float = 0.0
+    # frames fetched, by the id of the device their worker is pinned to
+    # (None: an unpinned worker)
+    frames_by_device: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter
+    )
+    sessions_opened: int = 0
     _lock: threading.Lock = dataclasses.field(default_factory=threading.Lock)
+
+    def record_span(self, name: str, ms: float) -> None:
+        with self._lock:
+            self.worker_ms[name] += ms
+
+    def record_route(self, ms: float) -> None:
+        with self._lock:
+            self.route_ms += ms
+
+    def record_session(self) -> None:
+        with self._lock:
+            self.sessions_opened += 1
 
     def record_prep(self, ms: float) -> None:
         with self._lock:
             self.prep_ms.append(ms)
+            self.worker_ms["canny.prep"] += ms
 
-    def record_compute(self, ms: float, host: str | None = None) -> None:
+    def record_compute(
+        self, ms: float, host: str | None = None, device: int | None = None
+    ) -> None:
         with self._lock:
             self.compute_ms.append(ms)
+            self.worker_ms["canny.fetch"] += ms
+            self.frames_by_device[device] += 1
             if self.watchdog is not None:
                 report = self.watchdog.observe(
                     ms / 1e3, {host: ms / 1e3} if host else None
@@ -230,8 +277,14 @@ class StreamWorker:
     Each frame passes through five host spans (``core/spans.py``):
     ``canny.prep`` (the float32 copy, into ``StreamStats.prep_ms``),
     ``canny.put`` and ``canny.step`` (in the pipeline), ``canny.fetch``
-    (the blocking edge fetch, into ``compute_ms`` and the watchdog) and
-    ``canny.cost_sync`` (the cost scalars read back to the host).
+    (the blocking edge fetch, into ``compute_ms``, ``frames_by_device``
+    and the watchdog) and ``canny.cost_sync`` (the cost scalars read back
+    to the host); each span's ms also adds to ``StreamStats.worker_ms``.
+
+    Given ``sessions`` (session mode), items are ``(camera, frame)`` pairs,
+    each stepped by its camera's session, and results ``(camera, edges)``.
+    A frame's result is handed back before the wait for the next frame
+    when none is queued (the farm's ``WorkerFeed.ready``).
 
     ``rank``/``injector`` are the fault-injection hook: the injector's
     schedule is consulted before every frame this worker computes, so a
@@ -248,6 +301,7 @@ class StreamWorker:
         name: str | None = None,
         rank: int = 0,
         injector: FaultInjector | None = None,
+        sessions: "SessionTable | None" = None,
     ):
         self.step = step
         self.stats = stats
@@ -255,31 +309,83 @@ class StreamWorker:
         self.name = name
         self.rank = rank
         self.injector = injector
-
-    def _run_step(self, x):
-        if self.injector is not None:
-            self.injector.before_frame(self.rank)
-        out = self.step(x)
-        return out if isinstance(out, tuple) else (out, None)
+        self.sessions = sessions
+        self._device_id = None if device is None else device.id
+        self._cost_sink = functools.partial(stats.record_span, "canny.cost_sync")
 
     def _record_fetch(self, ms: float) -> None:
-        self.stats.record_compute(ms, self.name)
+        self.stats.record_compute(ms, self.name, self._device_id)
 
-    def stream(self, frames: Iterable[np.ndarray]) -> Iterator[np.ndarray]:
+    def restarted(self) -> "StreamWorker":
+        """A fresh worker for this one's farm slot: the same step, device,
+        name and rank, and its sessions, if any, closed, so each reopens
+        cold (a dead worker's warm/skip state is untrustworthy; cold is
+        always exact, so only sweep cost is lost)."""
+        if self.sessions is not None:
+            self.sessions.reset()
+        return StreamWorker(
+            self.step, self.stats, self.device, name=self.name, rank=self.rank,
+            injector=self.injector, sessions=self.sessions,
+        )
+
+    def stream(self, frames: Iterable) -> Iterator:
+        cameras: collections.deque = collections.deque()  # session mode, in feed order
+
         def prepped():  # prep spanned here: the pipeline runs it one frame ahead
             for f in frames:
+                if self.sessions is not None:
+                    camera, f = f
+                    cameras.append(camera)
                 with span("canny.prep", self.stats.record_prep):
                     arr = np.asarray(f, np.float32)
                 yield arr
 
-        pipe = PatternPipeline(self._run_step, sharding=self.device)
-        for edges, cost in pipe.run(prepped()):
+        def run_step(x):  # the pipeline steps frames in feed order
+            if self.injector is not None:
+                self.injector.before_frame(self.rank)
+            if self.sessions is None:
+                camera, out = None, self.step(x)
+            else:
+                camera = cameras.popleft()
+                out = self.sessions.step(camera, x)
+            return camera, (out if isinstance(out, tuple) else (out, None))
+
+        pipe = PatternPipeline(run_step, sharding=self.device, record=self.stats.record_span)
+        for camera, (edges, cost) in pipe.run(prepped(), getattr(frames, "ready", None)):
             with span("canny.fetch", self._record_fetch):
                 out = np.asarray(edges)  # blocks until the device result lands
             if cost is not None:
-                with span("canny.cost_sync"):  # one device sync per cost scalar
+                with span("canny.cost_sync", self._cost_sink):  # one device sync per cost scalar
                     self.stats.record_cost(*(int(c) for c in cost))
-            yield out
+            yield out if self.sessions is None else (camera, out)
+
+
+class SessionTable:
+    """One worker's sessions: camera → that camera's own ``TemporalCanny``.
+
+    A session opens cold on its camera's first frame; its warm and skip
+    state then lives on the worker's chip (``PackedTemporal`` makes state
+    where the frame is). Every session shares the module-level jitted
+    step (``kernels/canny_backends.py:_make_step_fn``), so opening one
+    compiles nothing that an earlier session on the chip compiled.
+    """
+
+    def __init__(self, make: Callable[[], TemporalCanny], stats: StreamStats, device=None):
+        self.make = make
+        self.stats = stats
+        self.device = device
+        self.table: dict = {}
+
+    def step(self, camera, x):
+        t = self.table.get(camera)
+        if t is None:
+            t = self.table[camera] = self.make()
+            self.stats.record_session()
+        return t.step(x)
+
+    def reset(self) -> None:
+        """Close every session: the next frame of each camera opens cold."""
+        self.table.clear()
 
 
 class FarmScheduler:
@@ -303,7 +409,15 @@ class FarmScheduler:
     ranks — the same seq→rank map the multi-host harness uses — and the
     farm's seq-keyed reorder buffer IS the rank-tagged reassembly, so
     emission stays globally in order and bit-identical to one host
-    (``stream/pod.py``, pinned by ``tests/subproc/pod_farm.py``).
+    (``stream/pod.py``, pinned by ``tests/subproc/pod_farm.py``). A rank
+    whose slice is one device has its worker pinned to that device.
+
+    On the stateful local path (no ``dist`` mesh, no shared ``detector``)
+    the scheduler also serves many cameras: ``run_sessions`` (module
+    docstring) over ``WORKERS_PER_CHIP`` workers on each of ``devices``,
+    ``n_workers`` applying to ``run`` alone. That farm, its workers and
+    their ``sessions`` are built on the first ``run_sessions`` call, so a
+    scheduler that only runs one stream holds one farm.
     """
 
     def __init__(
@@ -325,7 +439,7 @@ class FarmScheduler:
     ):
         devices = list(devices) if devices is not None else jax.local_devices()
         if n_workers is None:
-            n_workers = max(2, len(devices))
+            n_workers = max(WORKERS_PER_CHIP, len(devices))
         self.params = params
         self.warm = warm
         self.dist = dist
@@ -336,6 +450,10 @@ class FarmScheduler:
         self.stats.watchdog = watchdog if watchdog is not None else StepWatchdog()
         self.detectors: list = []
         self.pods: list = []
+        self.sessions: list[SessionTable] = []
+        self.session_farm: Farm | None = None
+        self._open_sessions: Callable[[], Farm] | None = None
+        self._roster: tuple[int, ...] = ()
         if detector is None and dist is not None and dist.pod_size() > 1:
             # pod farm: worker k IS pod rank k (Farm's round-robin gives
             # it frames k, k+P, … — exactly PodCtx(k, P).owns). The worker
@@ -350,23 +468,24 @@ class FarmScheduler:
                 backend=backend, block_rows=block_rows,
             )
             self.detectors = [w.temporal for w in self.pods if w.temporal]
+            # a one-device rank is pinned to its device; a sub-mesh rank's
+            # shard_map owns placement
+            rank_device = [
+                devs.flat[0] if devs.size == 1 else None
+                for devs in map(dist.pod_devices, range(len(self.pods)))
+            ]
             workers = [
                 StreamWorker(
-                    w.step, self.stats,
+                    w.step, self.stats, rank_device[k],
                     name=f"rank{k}", rank=k, injector=injector,
                 )
                 for k, w in enumerate(self.pods)
             ]
 
             def remake_rank(k: int) -> StreamWorker:
-                # cold restart: the dead incarnation's warm/skip state is
-                # untrustworthy (PodWorker.reset docstring) — and cold is
-                # always bit-exact, so only sweep cost is lost
+                # cold restart (PodWorker.reset docstring)
                 self.pods[k].reset()
-                return StreamWorker(
-                    self.pods[k].step, self.stats,
-                    name=f"rank{k}", rank=k, injector=injector,
-                )
+                return self.farm.workers[k].restarted()
 
             self.farm = Farm(
                 workers, queue_depth=queue_depth,
@@ -450,27 +569,82 @@ class FarmScheduler:
             # shared detectors are stateless, reused as-is)
             if k < len(self.detectors):
                 self.detectors[k].reset()
-            old = self.farm.workers[k]
-            return StreamWorker(
-                old.step, self.stats, old.device,
-                name=old.name, rank=k, injector=injector,
-            )
+            return self.farm.workers[k].restarted()
 
         self.farm = Farm(
             workers, queue_depth=queue_depth,
             max_restarts=max_restarts, worker_factory=remake_worker,
             timeout=timeout,
         )
+        if detector is not None:
+            return
+
+        self._roster = tuple(range(len(devices)))
+
+        def open_sessions() -> Farm:
+            def make() -> TemporalCanny:
+                return TemporalCanny(
+                    params, warm=warm, skip=skip,
+                    backend=backend, block_rows=block_rows,
+                )
+
+            # session worker k serves chip k % len(devices) (session_route)
+            self.sessions = [
+                SessionTable(make, self.stats, devices[k % len(devices)])
+                for k in range(WORKERS_PER_CHIP * len(devices))
+            ]
+            farm = Farm(
+                [
+                    StreamWorker(
+                        None, self.stats, table.device, name=f"session{k}",
+                        rank=k, injector=injector, sessions=table,
+                    )
+                    for k, table in enumerate(self.sessions)
+                ],
+                queue_depth=queue_depth, max_restarts=max_restarts,
+                worker_factory=lambda k: farm.workers[k].restarted(),
+                timeout=timeout,
+            )
+            return farm
+
+        self._open_sessions = open_sessions
+
+    def _emit(self, farm: Farm, results: Iterator) -> Iterator:
+        t0 = time.perf_counter()
+        for out in results:
+            self.stats.frames += 1
+            self.stats.queue_depth.append(sum(farm.queue_depths()))
+            self.stats.restarts = farm.restarts
+            self.stats.wall_s = time.perf_counter() - t0
+            yield out
 
     def run(self, source: Iterable[np.ndarray]) -> Iterator[np.ndarray]:
         """Yield uint8 edge maps in frame order; updates ``self.stats``."""
-        t0 = time.perf_counter()
-        for edges in self.farm.run(source):
-            self.stats.frames += 1
-            self.stats.queue_depth.append(sum(self.farm.queue_depths()))
-            self.stats.restarts = self.farm.restarts
-            self.stats.wall_s = time.perf_counter() - t0
-            yield edges
+        return self._emit(self.farm, self.farm.run(source))
+
+    def route(self, camera: int) -> int:
+        """The session worker of ``camera`` (``pod.session_route`` over
+        the local chips)."""
+        return session_route(camera, self._roster, WORKERS_PER_CHIP)
+
+    def run_sessions(self, source: Iterable[tuple[int, np.ndarray]]) -> Iterator[tuple[int, np.ndarray]]:
+        """Session mode: ``(camera, frame)`` pairs in, ``(camera, uint8
+        edges)`` out in feed order, so each camera's in that camera's
+        order. Camera ids are ints >= 0; every frame of a camera goes to
+        the one worker ``route`` names."""
+        if self.session_farm is None:
+            if self._open_sessions is None:
+                raise ValueError(
+                    "session mode keeps per-camera TemporalCanny state on the "
+                    "local chips: it needs the stateful local path (no mesh "
+                    "dist, no shared detector)"
+                )
+            self.session_farm = self._open_sessions()
+        farm = self.session_farm
+        return self._emit(farm, farm.run(
+            source, route=lambda item: self.route(item[0]),
+            route_sink=self.stats.record_route,
+        ))
 
     def run_engine(
         self,

@@ -45,7 +45,7 @@ from repro.core.canny.hysteresis import (
     warm_seed,
 )
 from repro.core.canny.params import CannyParams
-from repro.core.patterns.dist import LOCAL, Dist, StencilCtx
+from repro.core.patterns.dist import LOCAL, Dist, StencilCtx, on_device_of
 
 
 class JnpTemporal:
@@ -77,7 +77,6 @@ class JnpTemporal:
             donate = jax.devices()[0].platform in ("tpu", "gpu")
         self.donate = bool(donate) and warm
         self._step = self._make_step()
-        self._have_true = jnp.ones((), bool)
         self.reset()
 
     def reset(self) -> None:
@@ -85,6 +84,7 @@ class JnpTemporal:
         self._prev_frame = None
         self._prev_nms = None
         self._have_prev = None
+        self._have_true = None
 
     def _make_step(self) -> Callable:
         from repro.core.canny.gaussian import gaussian_stage
@@ -135,13 +135,15 @@ class JnpTemporal:
     def step(self, x: jax.Array):
         b, h, w = x.shape
         if self._state is None:
-            # distinct zero buffers: donated args must not share a buffer
-            self._state = tuple(jnp.zeros((b, h, w), bool) for _ in range(3))
-            self._prev_frame = jnp.zeros((b, h, w), jnp.float32)
-            self._prev_nms = jnp.zeros((b, h, w), jnp.float32)
-        if self._have_prev is None:
-            # device-resident gate: one transfer per reset, none per frame
-            self._have_prev = jnp.zeros((), bool)
+            # made on the frame's device; distinct zero buffers: donated
+            # args must not share a buffer; the device-resident gate: one
+            # transfer per reset, none per frame
+            with on_device_of(x):
+                self._state = tuple(jnp.zeros((b, h, w), bool) for _ in range(3))
+                self._prev_frame = jnp.zeros((b, h, w), jnp.float32)
+                self._prev_nms = jnp.zeros((b, h, w), jnp.float32)
+                self._have_prev = jnp.zeros((), bool)
+                self._have_true = jnp.ones((), bool)
         if self.skip:
             edges, nms, state, cost = self._step(
                 x, self._prev_frame, self._prev_nms, *self._state,

@@ -108,3 +108,34 @@ def test_a_span_passes_its_duration_to_the_sink_once_its_body_completes():
         with span("canny.test", got.append):
             raise ValueError("body failed")
     assert len(got) == 1  # a failed body records nothing
+
+
+def _traced_ms(threads) -> dict[str, float]:
+    out: dict[str, float] = {}
+    for spans in threads:
+        for s, e, name in spans:
+            out[name] = out.get(name, 0.0) + (e - s) / 1e6
+    return out
+
+
+def test_a_routed_frame_has_one_route_span_on_the_feeder(tmp_path):
+    frames = list(SyntheticStream(6, 32, 64, seed=3))
+    feed = [(k % 3, f) for k, f in enumerate(frames)]  # 3 cameras, interleaved
+    farm = FarmScheduler(PARAMS, warm=True, skip=True, backend="jnp")
+    out = []
+    threads = _traced(tmp_path, lambda: out.extend(farm.run_sessions(feed)))
+    assert [c for c, _ in out] == [c for c, _ in feed]
+    (feeder,) = [s for s in threads if any(n == "canny.route" for _, _, n in s)]
+    assert [n for _, _, n in feeder] == ["canny.route"] * len(frames)
+    assert _counts(threads) == {**dict.fromkeys(STREAM_PHASES, len(frames)),
+                                "canny.route": len(frames)}
+    _assert_disjoint(threads)
+    # the sinks pass each span's own duration to StreamStats: its sums are
+    # the trace's, the few microseconds of the annotation itself aside
+    traced = _traced_ms(threads)
+    summed = {**farm.stats.worker_ms, "canny.route": farm.stats.route_ms}
+    assert set(summed) == set(traced)
+    for name, ms in traced.items():
+        assert summed[name] == pytest.approx(ms, rel=0.02, abs=2.0 * len(frames)), name
+    assert farm.stats.worker_ms["canny.prep"] == pytest.approx(sum(farm.stats.prep_ms))
+    assert farm.stats.worker_ms["canny.fetch"] == pytest.approx(sum(farm.stats.compute_ms))

@@ -12,6 +12,7 @@ The three properties the farm-of-pipelines design rests on:
   3. **Sources are deterministic/seekable** so streams replay exactly.
 """
 
+import collections
 import functools
 
 import jax
@@ -540,6 +541,161 @@ def test_farm_scheduler_skip_matches_cold():
     # hold=3 with 2 workers: each worker sees held repeats → must skip
     assert skip.stats.frontend_launches < len(frames)
     assert cold.stats.frontend_launches == len(frames)
+
+
+# ---------------- session mode: many cameras, one session each --------------
+SESSION_RATES = (30.0, 30.0, 25.0, 25.0, 12.5, 30.0)  # Hz, camera c at SESSION_RATES[c]
+
+
+def _capture_order(streams, rates, seconds):
+    """``(camera, frame_index)`` of every frame captured in ``[0,
+    seconds)``, ordered by capture time (camera c's frame i at ``(i +
+    phase_c) / rate_c``, phases spread over a frame period)."""
+    stamps = []
+    for c, rate in enumerate(rates):
+        phase = (c % 3) / 3.0
+        i = 0
+        while (i + phase) / rate < seconds and i < len(streams[c]):
+            stamps.append(((i + phase) / rate, c, i))
+            i += 1
+    return [(c, i) for _, c, i in sorted(stamps)]
+
+
+@functools.lru_cache(maxsize=1)
+def _session_feed():
+    # one moving object in 8 strips: most strips of a camera's frame are static
+    streams = [list(SyntheticStream(8, 128, 64, seed=40 + c, n_moving=1))
+               for c in range(len(SESSION_RATES))]
+    order = _capture_order(streams, SESSION_RATES, 0.25)
+    return streams, order
+
+
+def test_session_mode_exact_in_order_and_cheaper_than_round_robin():
+    streams, order = _session_feed()
+    sched = FarmScheduler(PARAMS, warm=True, skip=True, block_rows=16)
+    got = list(sched.run_sessions((c, streams[c][i]) for c, i in order))
+    # one result per frame fed, in feed order: each camera's in its own order
+    assert [c for c, _ in got] == [c for c, _ in order]
+    counts = collections.Counter(c for c, _ in order)
+    assert counts[0] > counts[2] > counts[4]  # 30 : 25 : 12.5 Hz
+    cold = {c: TemporalCanny(PARAMS, warm=False, block_rows=16) for c in counts}
+    for (c, i), (_, edges) in zip(order, got):
+        frame = streams[c][i]
+        assert (edges == canny_reference(frame, PARAMS)).all(), (c, i)
+        assert (edges == np.asarray(cold[c](jnp.asarray(frame)))).all(), (c, i)
+    # one session per camera, each on the one worker the router names
+    assert sched.stats.sessions_opened == len(counts)
+    held = {c: k for k, table in enumerate(sched.sessions) for c in table.table}
+    assert held == {c: sched.route(c) for c in counts}
+    # the same feed dispatched seq % n: every worker's previous frame is
+    # another camera's, so the skip finds fewer static strips
+    plain = FarmScheduler(PARAMS, n_workers=len(sched.sessions), warm=True,
+                          skip=True, block_rows=16)
+    list(plain.run(streams[c][i] for c, i in order))
+    assert sched.stats.frontend_strips < plain.stats.frontend_strips
+
+
+def test_session_route_is_pure_in_camera_and_roster():
+    from repro.stream.pod import session_route
+
+    roster = (0, 1, 2, 3)
+    workers = [session_route(c, roster, 2) for c in range(40)]
+    assert workers == [session_route(c, roster, 2) for c in reversed(range(40))][::-1]
+    # worker k serves chip k % 4; every chip 10 cameras, every worker 5
+    assert all(w % 4 == c % 4 for c, w in enumerate(workers))
+    assert collections.Counter(workers) == dict.fromkeys(range(8), 5)
+
+
+def test_farm_route_dispatches_by_key_in_feed_order():
+    seen = collections.defaultdict(list)
+
+    def worker(k):
+        def run(item):
+            seen[k].append(item)
+            return item * 10
+        return run
+
+    farm = Farm([worker(k) for k in range(3)], queue_depth=1)
+    got = list(farm.run(range(30), route=lambda item: item % 7 % 3))
+    assert got == [x * 10 for x in range(30)]
+    assert {k: sorted(v) for k, v in seen.items()} == {
+        k: [x for x in range(30) if x % 7 % 3 == k] for k in range(3)
+    }
+
+
+class _Lookahead:
+    """A ``.stream`` worker that, like ``PatternPipeline``, holds an item's
+    result until it has the next item, unless ``ready()`` says none is
+    queued (``WorkerFeed``)."""
+
+    def stream(self, items):
+        held = None
+        for item in items:
+            if held is not None:
+                yield held * 10
+            held = item
+            if not items.ready():
+                yield held * 10
+                held = None
+        if held is not None:
+            yield held * 10
+
+
+def test_farm_route_reaches_a_pipelined_workers_next_item_far_ahead():
+    """Worker 0's next item comes 50 items after its first; the routed
+    feeder, which keeps at most n · (queue_depth + 2) items fed but not
+    emitted, gets there because the worker hands its first result back
+    when nothing is queued behind it."""
+    route = lambda item: 0 if item in (0, 51) else 1  # noqa: E731
+    farm = Farm([_Lookahead(), _Lookahead()], queue_depth=1, timeout=30.0)
+    assert list(farm.run(range(60), route=route)) == [x * 10 for x in range(60)]
+
+
+def test_farm_route_emits_a_pipelined_workers_result_before_its_next_item():
+    """Worker 0 gets item 0 and nothing after it (its camera paused), the
+    other workers every later item: item 0 comes out while the feed still
+    runs, and the feeder never runs more than n · (queue_depth + 2) items
+    ahead of the consumer, so the reorder buffer stays bounded."""
+    from repro.stream.scheduler import StreamStats, StreamWorker
+
+    n, depth, total = 3, 1, 60
+    stats = StreamStats()
+    farm = Farm([StreamWorker(lambda x: x + 0, stats) for _ in range(n)],
+                queue_depth=depth, timeout=30.0)
+    pulled = []
+
+    def feed():
+        for k in range(total):
+            pulled.append(k)
+            yield np.full((4, 4), k, np.float32)
+
+    def route(frame):
+        k = int(frame[0, 0])
+        return 0 if k == 0 else 1 + k % (n - 1)
+
+    got, ahead = [], []
+    for edges in farm.run(feed(), route=route):
+        got.append(int(edges[0, 0]))
+        ahead.append(len(pulled) - len(got))
+    assert got == list(range(total))
+    assert ahead[0] + 1 < total // 2  # item 0 out with most of the feed still to come
+    # one more than the window: the feeder holds the item it waits to enqueue
+    assert max(ahead) <= n * (depth + 2) + 1
+
+
+def test_session_mode_survives_a_camera_far_ahead_in_the_feed():
+    streams, _ = _session_feed()
+    feed = [(0, streams[0][0])] + [(1, streams[1][i % 8]) for i in range(40)] + [(0, streams[0][1])]
+    sched = FarmScheduler(PARAMS, warm=True, skip=True, block_rows=16, timeout=60.0)
+    got = list(sched.run_sessions(feed))
+    assert [c for c, _ in got] == [c for c, _ in feed]
+    assert all((e == canny_reference(f, PARAMS)).all() for (_, f), (_, e) in zip(feed, got))
+
+
+def test_session_mode_needs_the_stateful_local_path():
+    sched = FarmScheduler(PARAMS, n_workers=2, detector=lambda x: x)
+    with pytest.raises(ValueError, match="session mode"):
+        list(sched.run_sessions([(0, np.zeros((8, 8), np.float32))]))
 
 
 # ---------------- elastic plane ----------------------------------------------
