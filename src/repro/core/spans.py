@@ -17,7 +17,8 @@ inside a jitted or Pallas function, and never adding a device sync.
 The spans and the counters their sinks feed:
 
 - ``canny.wait``, ``canny.pack``: the serving dispatch thread
-  (``serve/admission.py``), no sink;
+  (``serve/admission.py``), no sink; ``StreamStats.arena_lanes`` counts
+  the lanes packed into the batcher's one host arena;
 - ``canny.put``, ``canny.step``, ``canny.fetch``: the serving dispatch
   thread (``serve/aot.py``), no sink; and the stream workers
   (``stream/scheduler.py:StreamWorker``), with ``canny.prep`` and

@@ -144,6 +144,16 @@ class ContinuousBatcher:
     ``canny.wait``, ``canny.pack`` and (in ``run_packed``) ``canny.put``,
     ``canny.step`` and ``canny.fetch`` spans (``core/spans.py``).
 
+    Every lane is packed into one float32 host arena, allocated and
+    written once here, sized to the lattice's largest cell (max of
+    lane · hb · wb): 141 MB for lanes of 4 A4 pages. A fresh batch that
+    large is a fresh ``mmap`` whose pages all fault on first touch, in
+    every lane; the arena's faults land in construction instead. One
+    arena suffices because the dispatch loop is serial: ``run_packed``
+    returns only once the device result is fetched, so the transfer that
+    read the last lane is over, and tickets get slices of that fetched
+    result, never of the arena. ``stats.arena_lanes`` counts its lanes.
+
     Use as a context manager, or call ``close()``; both flush open slots,
     stop the workers, and re-raise any recorded worker error.
     """
@@ -194,6 +204,16 @@ class ContinuousBatcher:
         self.submitted = 0
         self.completed = 0
         self._max_lane = max(engine.lanes)
+        # fill, not np.zeros: calloc maps lazy zero pages, which would
+        # fault on the first pack instead of here
+        self._arena = np.empty(
+            max(
+                lane * hb * wb
+                for lane in engine.lanes for hb, wb in engine.hw_buckets
+            ),
+            np.float32,
+        )
+        self._arena.fill(0)
         self._dispatcher = FailFast(
             target=self._dispatch_loop, daemon=True,
             name=f"{self.name}-dispatch", on_error=self._poison,
@@ -302,9 +322,13 @@ class ContinuousBatcher:
                 t.t_dispatch = t_dispatch
             self.stats.record_occupancy(len(taken), lane)
             with span("canny.pack"):
+                # the last lane's run_packed has fetched its result, so no
+                # transfer still reads the arena this lane overwrites
                 packed, true_hw = pack_requests(
-                    [self._images[id(t)] for t in taken], hb, wb, bb=lane
+                    [self._images[id(t)] for t in taken], hb, wb, bb=lane,
+                    out=self._arena[: lane * hb * wb].reshape(lane, hb, wb),
                 )
+            self.stats.record_arena_lane()
             out = self.engine.run_packed(packed, true_hw)  # blocks on device
             # bounded backlog: a slow drainer (or consumer) throttles the
             # NEXT launch instead of results buffering without limit

@@ -68,7 +68,7 @@ def bucket_batch(n: int, lane: int = 1) -> int:
 
 def pack_requests(
     images: Sequence[np.ndarray], hb: int, wb: int, bb: int | None = None,
-    lane: int = 1,
+    lane: int = 1, out: np.ndarray | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Pad a wave of (h, w) requests into one (bb, hb, wb) bucket batch
     plus its per-slot true-size table — the packing the lazy engine, the
@@ -80,12 +80,28 @@ def pack_requests(
     One write pass per page: the page is cast straight into its slot,
     then its last column and last row are broadcast into the pad —
     the same bytes as ``np.pad(img.astype(np.float32), mode="edge")``
-    without its two intermediate copies."""
+    without its two intermediate copies.
+
+    ``out=None`` packs into a fresh ``np.empty`` batch. Given ``out``, a
+    C-contiguous float32 (bb, hb, wb) array (``ValueError`` otherwise),
+    the batch is written there and ``out`` is returned. Every byte of it
+    is rewritten, so a dirty ``out`` gives the same batch as a fresh one."""
     if bb is None:
         bb = bucket_batch(len(images), lane)
     if len(images) > bb:
         raise ValueError(f"{len(images)} requests exceed batch bucket {bb}")
-    batch = np.empty((bb, hb, wb), np.float32)
+    if out is None:
+        batch = np.empty((bb, hb, wb), np.float32)
+    elif (
+        out.shape != (bb, hb, wb) or out.dtype != np.float32
+        or not out.flags.c_contiguous
+    ):
+        raise ValueError(
+            f"out must be a C-contiguous float32 {(bb, hb, wb)} array, got "
+            f"{out.dtype} {out.shape}"
+        )
+    else:
+        batch = out
     batch[len(images):] = 0
     true_hw = np.full((bb, 2), (hb, wb), np.int32)
     for slot, img in enumerate(images):

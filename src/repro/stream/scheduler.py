@@ -94,6 +94,8 @@ class StreamStats:
     slot_occupancy: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=4096)
     )
+    # lanes the batcher's dispatch thread packed into its one host arena
+    arena_lanes: int = 0
     slo_ms: float | None = None
     slo_pass: int = 0
     slo_fail: int = 0
@@ -188,6 +190,10 @@ class StreamStats:
         """How full a dispatched slot was (1.0 = the lane was packed)."""
         with self._lock:
             self.slot_occupancy.append(filled / lane)
+
+    def record_arena_lane(self) -> None:
+        with self._lock:
+            self.arena_lanes += 1
 
     def latency_ms(self, q: float) -> float:
         """q-quantile of per-request enqueue→complete latency (the SLO

@@ -78,6 +78,7 @@ def _page(rng, shape, dtype):
     return rng.random(shape).astype(dtype)
 
 
+@pytest.mark.parametrize("where", ["fresh", "dirty"])
 @pytest.mark.parametrize(
     "shapes,dtype,hb,wb,bb,lane",
     [
@@ -92,12 +93,19 @@ def _page(rng, shape, dtype):
         ([(3, 4), (6, 9)], np.float32, 8, 12, None, 3),        # bb from lane
     ],
 )
-def test_pack_requests_matches_np_pad(shapes, dtype, hb, wb, bb, lane):
+def test_pack_requests_matches_np_pad(shapes, dtype, hb, wb, bb, lane, where):
+    """``fresh``: today's call, a new batch; ``dirty``: into an ``out``
+    arena full of NaN, every byte of which the pack must rewrite."""
     rng = np.random.default_rng(len(shapes) * 100 + hb)
     images = [_page(rng, s, dtype) for s in shapes]
     before = [img.copy() for img in images]
-    batch, true_hw = pack_requests(images, hb, wb, bb=bb, lane=lane)
     want_bb = bucket_batch(len(images), lane) if bb is None else bb
+    if where == "fresh":
+        batch, true_hw = pack_requests(images, hb, wb, bb=bb, lane=lane)
+    else:
+        arena = np.full((want_bb, hb, wb), np.nan, np.float32)
+        batch, true_hw = pack_requests(images, hb, wb, bb=bb, lane=lane, out=arena)
+        assert batch is arena
     assert batch.dtype == np.float32 and batch.shape == (want_bb, hb, wb)
     np.testing.assert_array_equal(batch, _pack_oracle(images, hb, wb, want_bb))
     assert not batch[len(images):].any()
@@ -107,6 +115,23 @@ def test_pack_requests_matches_np_pad(shapes, dtype, hb, wb, bb, lane):
     for img, was in zip(images, before):
         np.testing.assert_array_equal(img, was)
         assert not np.shares_memory(batch, img)
+
+
+@pytest.mark.parametrize(
+    "out",
+    [
+        np.zeros((2, 8, 12), np.float64),                 # wrong dtype
+        np.zeros((2, 8, 16), np.float32),                 # wrong width
+        np.zeros((4, 8, 12), np.float32),                 # wrong batch
+        np.zeros((2, 8, 24), np.float32)[:, :, ::2],      # not contiguous
+    ],
+    ids=["dtype", "width", "batch", "strided"],
+)
+def test_pack_requests_rejects_a_wrong_out(out):
+    images = [np.ones((5, 7), np.float32)]
+    with pytest.raises(ValueError, match="out must be"):
+        pack_requests(images, 8, 12, bb=2, out=out)
+    assert not out.any()  # nothing written before the refusal
 
 
 def test_pack_requests_rejects_too_many_requests():

@@ -332,6 +332,52 @@ def test_batcher_scores_requests_against_slo():
         }
 
 
+def test_batcher_packs_every_lane_into_one_arena(monkeypatch):
+    """Lanes of two buckets, lane 4 each, the last one partial (flushed
+    at close), all packed into the arena made at construction: each
+    result equals the synchronous wave's, and results kept from a lane
+    do not move when later lanes overwrite the arena."""
+    from repro.serve import admission
+
+    engine = make_engine(buckets=[(32, 32), (32, 64)], max_batch=4)
+    outs = []
+    real = admission.pack_requests
+
+    def spy(*args, **kw):
+        outs.append(kw["out"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(admission, "pack_requests", spy)
+    groups = [
+        [synthetic_image(30, 60, seed=80 + i) for i in range(4)],   # whole arena
+        [synthetic_image(30, 30, seed=90 + i) for i in range(4)],   # leading half
+        [synthetic_image(20, 50, seed=100 + i) for i in range(4)],
+        [synthetic_image(32, 32, seed=110 + i) for i in range(3)],  # phantom slot
+    ]
+    want = [engine.process(g) for g in groups]
+    kept, copies = [], []
+    b = ContinuousBatcher(engine, linger_ms=60_000.0, timeout=60.0)
+    arena = b._arena
+    assert arena.dtype == np.float32 and arena.size == 4 * 32 * 64
+    with b:
+        for g in groups[:3]:  # full lanes: only the fill trigger fires
+            got = [t.result(timeout=60.0) for t in [b.submit(img) for img in g]]
+            kept.append(got)
+            copies.append([r.copy() for r in got])
+        last = [b.submit(img) for img in groups[3]]
+    kept.append([t.result() for t in last])
+    copies.append([r.copy() for r in kept[-1]])
+    assert b._arena is arena
+    for got, was, w in zip(kept, copies, want):
+        for r, c, ref in zip(got, was, w):
+            assert (r == c).all() and (r == ref).all()
+    assert len(outs) == 4
+    for out in outs:
+        assert out.ctypes.data == arena.ctypes.data and np.shares_memory(out, arena)
+    assert [o.shape for o in outs] == [(4, 32, 64), (4, 32, 32), (4, 32, 64), (4, 32, 32)]
+    assert b.stats.arena_lanes == len(b.stats.slot_occupancy) == 4
+
+
 # ---------------- scheduler integration --------------------------------------
 def test_run_engine_aot_mode_in_order_and_exact():
     from repro.stream.scheduler import FarmScheduler
