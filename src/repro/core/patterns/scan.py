@@ -1,9 +1,9 @@
 """Scan pattern — blocked associative scans.
 
 Used two ways in this framework:
-  * Mamba-2 SSD blocks (models/mamba.py) are a chunked scan: quadratic
-    intra-chunk work + an associative carry across chunks — exactly the
-    tile-then-combine structure the paper's patterns advocate.
+  * Chunked scans: work within each chunk + an associative carry across
+    chunks — exactly the tile-then-combine structure the paper's
+    patterns advocate.
   * Distributed scans across a sharded sequence axis: local scan, then a
     log-step Hillis–Steele carry across shards via ppermute.
 """
@@ -81,8 +81,8 @@ def pattern_scan(
     exclusive prefix of earlier shards into its local results. ``combine``
     must be leaf-wise (it is applied with the carry broadcast over the
     scanned axis), which covers cumsum/cummax/log-sum-exp style monoids;
-    structured monoids (e.g. SSD's (A, Bx) pairs) should use their own
-    carry chain — see ``models/mamba.py``.
+    structured monoids (e.g. a linear recurrence's (A, Bx) pairs) should
+    use their own carry chain.
     """
     local = lax.associative_scan(combine, elems, axis=axis)
     if axis_name is None:

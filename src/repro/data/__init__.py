@@ -1,10 +1,7 @@
 from repro.data.images import synthetic_image, synthetic_batch, save_pgm
-from repro.data.pipeline import ShardedBatcher, synthetic_token_stream
 
 __all__ = [
     "synthetic_image",
     "synthetic_batch",
     "save_pgm",
-    "ShardedBatcher",
-    "synthetic_token_stream",
 ]

@@ -1,12 +1,3 @@
-from repro.distributed.sharding import (
-    Rules,
-    activation_rules,
-    cache_rules,
-    opt_rules,
-    param_rules,
-    tree_shardings,
-    tree_specs,
-)
 from repro.distributed.fault_tolerance import (
     Backoff,
     DeviceFailure,
@@ -22,13 +13,6 @@ from repro.distributed.fault_tolerance import (
 )
 
 __all__ = [
-    "Rules",
-    "activation_rules",
-    "cache_rules",
-    "opt_rules",
-    "param_rules",
-    "tree_shardings",
-    "tree_specs",
     "Backoff",
     "DeviceFailure",
     "ElasticPlan",

@@ -1,9 +1,9 @@
 """JAX's persistent compile cache, placed from outside the program.
 
 Every entry point (``chip_smoke.py``, the ``launch/canny_*.py`` CLIs,
-``benchmarks/run.py``) calls ``use_compile_cache()`` once at start-up;
-importing the library never does, so tests and embedding programs keep
-whatever cache setting they chose.
+the benchmark's ``bench/harness.py``) calls ``use_compile_cache()``
+once at start-up; importing the library never does, so tests and
+embedding programs keep whatever cache setting they chose.
 """
 
 from __future__ import annotations

@@ -23,14 +23,14 @@ plumbing and consensus still execute; the CI conformance job forces 8
 virtual devices for a real 2×4 split; tests/test_sharded.py pins the
 multi-device bit-identity separately).
 
-The stream axes are chosen adversarially for the temporal paths:
-all-static (maximal skip), all-changing (skip must never fire wrongly),
-and single-pixel flicker (destructive edits every frame — the warm gate
-must fall back cold AND the strip mask must recompute exactly the
-touched strips). The cost-counter tests at the bottom parametrize over
-every skip-capable backend and pin the acceptance property: the
-per-stage path shows the SAME launch/strip savings as fused on a static
-stream.
+The stream axes are chosen adversarially for the temporal paths
+(``tests/_conformance.py``): all-static (maximal skip), all-changing (skip
+must never fire wrongly), and single-pixel flicker (destructive edits
+every frame — the warm gate must fall back cold AND the strip mask must
+recompute exactly the touched strips). The cost-counter tests at the
+bottom parametrize over every skip-capable backend and pin the
+acceptance property: the per-stage path shows the SAME launch/strip
+savings as fused on a static stream.
 """
 
 import jax
@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.canny import (
-    CannyParams,
     UnsupportedFeature,
     backend_specs,
     canny_reference,
@@ -50,9 +49,14 @@ from repro.core.patterns.dist import LOCAL, Dist, auto_mesh
 from repro.data.images import synthetic_image
 from repro.stream import TemporalCanny
 
-PARAMS = CannyParams(sigma=1.4, radius=2, low=0.08, high=0.2)
-# odd sizes on purpose: below-halo heights, non-multiple-of-32 widths
-CORPUS_SIZES = [(37, 53), (64, 96), (21, 33), (48, 64)]
+from _conformance import (
+    CORPUS_SIZES,
+    PARAMS,
+    STREAMS,
+    all_changing,
+    all_static,
+    single_pixel_flicker,
+)
 
 CELLS = list(conformance_cells())
 BY_NAME = {s.name: s for s in backend_specs()}
@@ -93,37 +97,6 @@ def _make_detector(cell):
         block_rows=16,
         dist=dist,
     )
-
-
-# ---------------- adversarial synthetic streams -----------------------------
-def _all_static(frames=4, h=48, w=64):
-    base = synthetic_image(h, w, seed=7)
-    return [base.copy() for _ in range(frames)]
-
-
-def _all_changing(frames=4, h=48, w=64):
-    return [synthetic_image(h, w, seed=200 + i) for i in range(frames)]
-
-
-def _single_pixel_flicker(frames=5, h=48, w=64):
-    """One pixel toggles a strong step every frame: destructive edits
-    (the warm gate must go cold) localized to one strip (the skip mask
-    must recompute only the strips whose halo sees the pixel)."""
-    base = synthetic_image(h, w, seed=9)
-    out = []
-    for i in range(frames):
-        f = base.copy()
-        if i % 2:
-            f[h // 2, w // 2] = 1.0
-        out.append(f)
-    return out
-
-
-STREAMS = {
-    "all-static": _all_static,
-    "all-changing": _all_changing,
-    "single-pixel-flicker": _single_pixel_flicker,
-}
 
 
 # ---------------- the generated matrix --------------------------------------
@@ -291,7 +264,7 @@ def test_scheduler_builds_a_single_lane_warm_mesh_temporal():
     assert len(sched.farm.workers) == 1
     assert len(sched.detectors) == 1
     assert not sched.detectors[0].dist.is_local
-    frames = _all_static(frames=3)
+    frames = all_static(frames=3)
     for i, edges in enumerate(sched.run(iter(frames))):
         assert (edges == canny_reference(frames[i], PARAMS)).all(), i
     assert sched.detectors[0].cost_totals()["frames"] == 3
@@ -333,7 +306,7 @@ def _frontend_launches_per_frame(name: str) -> int:
     """Measured, not assumed: frame 0 of a fresh stream reports how many
     front-end launches one full recompute costs (1 fused, 3 per-stage)."""
     det = TemporalCanny(PARAMS, warm=True, skip=True, backend=name, block_rows=16)
-    cost = det.step(jnp.asarray(_all_static(frames=1)[0]))[1]
+    cost = det.step(jnp.asarray(all_static(frames=1)[0]))[1]
     return int(cost[2])
 
 
@@ -344,7 +317,7 @@ def test_warm_skip_static_stream_saves_frontend_launches(name):
     hysteresis sweep with zero productive dilations — the SAME savings
     counters on every backend, per-stage included (acceptance criterion)."""
     det = TemporalCanny(PARAMS, warm=True, skip=True, backend=name, block_rows=16)
-    costs = [det.step(jnp.asarray(f))[1] for f in _all_static(frames=5)]
+    costs = [det.step(jnp.asarray(f))[1] for f in all_static(frames=5)]
     tot = det.cost_totals()
     assert tot["frontend_launches"] == int(costs[0][2]), tot
     for cost in costs[1:]:
@@ -365,7 +338,7 @@ def test_per_stage_static_savings_match_fused():
         det = TemporalCanny(PARAMS, warm=True, skip=True, backend=name, block_rows=16)
         costs[name] = [
             tuple(int(c) for c in det.step(jnp.asarray(f))[1])
-            for f in _all_static(frames=5)
+            for f in all_static(frames=5)
         ]
     assert costs["pallas"][1:] == costs["fused"][1:]
     assert all(c == (1, 0, 0, 0) for c in costs["fused"][1:])
@@ -388,7 +361,7 @@ def test_warm_mesh_launch_parity_on_static_stream(name):
     )
     det_l = TemporalCanny(PARAMS, warm=True, skip=True, backend=name, block_rows=16)
     costs_m, costs_l = [], []
-    for f in _all_static(frames=5):
+    for f in all_static(frames=5):
         costs_m.append(tuple(int(c) for c in det_m.step(jnp.asarray(f))[1]))
         costs_l.append(tuple(int(c) for c in det_l.step(jnp.asarray(f))[1]))
     assert costs_m[1:] == costs_l[1:]
@@ -398,7 +371,7 @@ def test_warm_mesh_launch_parity_on_static_stream(name):
 @pytest.mark.parametrize("name", SKIP_BACKENDS)
 def test_warm_skip_changing_stream_never_skips(name):
     det = TemporalCanny(PARAMS, warm=True, skip=True, backend=name, block_rows=16)
-    frames = _all_changing(frames=4)
+    frames = all_changing(frames=4)
     per_frame = _frontend_launches_per_frame(name)
     for frame in frames:
         det.step(jnp.asarray(frame))
@@ -413,7 +386,7 @@ def test_warm_skip_flicker_recomputes_only_touched_strips(name):
     other strip must come from the stored front-end output — on the
     per-stage path this holds PER STAGE (each stage its own mask)."""
     det = TemporalCanny(PARAMS, warm=True, skip=True, backend=name, block_rows=16)
-    frames = _single_pixel_flicker(frames=5, h=48, w=64)
+    frames = single_pixel_flicker(frames=5, h=48, w=64)
     n_strips = 48 // 16
     per_frame = _frontend_launches_per_frame(name)
     for frame in frames:
@@ -429,7 +402,7 @@ def test_warm_skip_flicker_recomputes_only_touched_strips(name):
 
 def test_jnp_warm_skip_static_stream_saves_frontend_launches():
     det = TemporalCanny(PARAMS, warm=True, skip=True, backend="jnp")
-    for frame in _all_static(frames=4):
+    for frame in all_static(frames=4):
         det.step(jnp.asarray(frame))
     tot = det.cost_totals()
     assert tot["frontend_launches"] == 1, tot

@@ -47,12 +47,3 @@ def test_elastic_checkpoint_restore():
     assert "elastic restore: OK" in out
     assert "elastic pod re-bucketing (4 -> 3 -> 4 ranks): OK" in out
 
-
-def test_moe_expert_parallel_variants():
-    out = run_with_devices("moe_ep.py", n_devices=8)
-    assert "ALL-OK" in out
-
-
-def test_pipeline_parallel_gpipe():
-    out = run_with_devices("pipeline_pp.py", n_devices=4)
-    assert "ALL-OK" in out
