@@ -18,9 +18,11 @@ The spans and the counters their sinks feed:
 
 - ``canny.wait``, ``canny.pack``: the serving dispatch thread
   (``serve/admission.py``), no sink; ``StreamStats.arena_lanes`` counts
-  the lanes packed into the batcher's one host arena;
-- ``canny.put``, ``canny.step``, ``canny.fetch``: the serving dispatch
-  thread (``serve/aot.py``), no sink; and the stream workers
+  the lanes packed into the batcher's host arenas, and
+  ``StreamStats.overlapped_lanes`` those launched while an earlier lane's
+  ``run_packed`` had not yet returned;
+- ``canny.put``, ``canny.step``, ``canny.fetch``: the serving runner
+  threads (``serve/aot.py:run_packed``), no sink; and the stream workers
   (``stream/scheduler.py:StreamWorker``), with ``canny.prep`` and
   ``canny.cost_sync``: each adds its ms to ``StreamStats.worker_ms[name]``;
   ``canny.prep`` also feeds ``prep_ms``, and ``canny.fetch``

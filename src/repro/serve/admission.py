@@ -16,14 +16,17 @@ on Canny buckets):
   * **dispatch** — a dedicated fail-fast thread packs each accumulator
     into the smallest precompiled batch lane the moment the largest lane
     FILLS or the oldest request's ``linger_ms`` deadline expires; no
-    request ever waits on an unrelated bucket. Slot occupancy and queue
-    depth land in ``StreamStats`` gauges.
-  * **completion** — launches push onto a BOUNDED result backlog drained
-    by a second fail-fast thread that crops per-request results, stamps
-    completion, resolves tickets, and scores the request against the
-    ``slo_ms`` bound. The bounded backlog is backpressure: a slow
-    consumer throttles dispatch instead of buffering results without
-    limit.
+    request ever waits on an unrelated bucket. It hands the packed lane
+    to a runner thread (``engine.run_packed``) and goes back for the
+    next, so on a local engine two lanes are in flight at once. Slot
+    occupancy and queue depth land in ``StreamStats`` gauges.
+  * **completion** — launches push onto a result backlog, in launch
+    order, drained by a second fail-fast thread that waits for each
+    launch, crops per-request results, stamps completion, resolves
+    tickets, and scores the request against the ``slo_ms`` bound. Each
+    launch holds one of the batcher's host arenas until it is drained,
+    so the arenas are the backpressure: a slow drain throttles dispatch
+    instead of results buffering without limit.
 
 Any worker exception (dispatch or drain) POISONS the batcher: it is
 recorded, every blocked call (``submit``, ``Ticket.result``, ``drain``)
@@ -45,11 +48,11 @@ import collections
 import queue
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
 
 import numpy as np
 
-from repro.core.patterns.farm import put_cancellable
 from repro.core.spans import span
 from repro.distributed.fault_tolerance import FailFast, StreamTimeout, wait_for
 from repro.serve.aot import AotCannyEngine
@@ -136,23 +139,30 @@ class ContinuousBatcher:
     """Continuous admission over an ``AotCannyEngine``.
 
     ``submit`` → ``SloTicket``; a dispatch thread packs open bucket slots
-    (fill-or-linger), a drain thread resolves results from a bounded
-    backlog. ``stats`` (a ``stream.scheduler.StreamStats``) accumulates
+    (fill-or-linger) and hands each lane to a runner thread, a drain
+    thread resolves the lanes' results in launch order. ``stats`` (a ``stream.scheduler.StreamStats``) accumulates
     the per-request SLO plane: enqueue→complete latency samples,
     p50/p95/p99, queue-depth + slot-occupancy gauges, and the pass/fail
     counter against ``slo_ms``. The dispatch thread's phases are the
-    ``canny.wait``, ``canny.pack`` and (in ``run_packed``) ``canny.put``,
-    ``canny.step`` and ``canny.fetch`` spans (``core/spans.py``).
+    ``canny.wait`` and ``canny.pack`` spans, the runner threads' (in
+    ``run_packed``) ``canny.put``, ``canny.step`` and ``canny.fetch``
+    (``core/spans.py``).
 
-    Every lane is packed into one float32 host arena, allocated and
-    written once here, sized to the lattice's largest cell (max of
-    lane · hb · wb): 141 MB for lanes of 4 A4 pages. A fresh batch that
-    large is a fresh ``mmap`` whose pages all fault on first touch, in
-    every lane; the arena's faults land in construction instead. One
-    arena suffices because the dispatch loop is serial: ``run_packed``
-    returns only once the device result is fetched, so the transfer that
-    read the last lane is over, and tickets get slices of that fetched
-    result, never of the arena. ``stats.arena_lanes`` counts its lanes.
+    Every lane is packed into one of ``depth`` float32 host arenas,
+    allocated and written once here, each sized to the lattice's largest
+    cell (max of lane · hb · wb): 141 MB for lanes of 4 A4 pages. A
+    fresh batch that large is a fresh ``mmap`` whose pages all fault on
+    first touch, in every lane; the arenas' faults land in construction
+    instead. ``depth`` is 2 on a local engine: the next lane packs into
+    the second arena and launches while the last one is still fetching.
+    A mesh engine serialises ``run_packed`` through its fetch (its mesh
+    lock), so there ``depth`` is 1 and lanes run one at a time. An arena
+    goes back to the dispatch thread only once the drain thread has its
+    lane's ``run_packed`` result: the transfer that read it is then
+    over, and tickets get slices of that fetched result, never of the
+    arena. ``stats.arena_lanes`` counts the lanes packed into the arenas,
+    ``stats.overlapped_lanes`` those launched while an earlier lane's
+    ``run_packed`` had not yet returned.
 
     Use as a context manager, or call ``close()``; both flush open slots,
     stop the workers, and re-raise any recorded worker error.
@@ -163,7 +173,6 @@ class ContinuousBatcher:
         engine: AotCannyEngine,
         linger_ms: float = 5.0,
         max_pending: int | None = None,
-        backlog: int = 8,
         slo_ms: float | None = None,
         timeout: float | None = None,
         stats=None,
@@ -174,8 +183,6 @@ class ContinuousBatcher:
             raise ValueError("linger_ms must be >= 0")
         if max_pending is not None and max_pending < 1:
             raise ValueError("max_pending must be >= 1 (or None for unbounded)")
-        if backlog < 1:
-            raise ValueError("backlog must be >= 1")
         if timeout is not None and timeout <= 0:
             raise ValueError("timeout must be positive (or None for unbounded)")
         if stats is None:
@@ -197,23 +204,27 @@ class ContinuousBatcher:
             hw: _Accumulator() for hw in engine.hw_buckets
         }
         self._images: dict[int, np.ndarray] = {}  # id(ticket) → request
-        self._backlog: queue.Queue = queue.Queue(maxsize=backlog)
+        self._backlog: queue.Queue = queue.Queue()  # at most depth entries
         self._error: BaseException | None = None
         self._stop = threading.Event()
         self._flush = False
         self.submitted = 0
         self.completed = 0
         self._max_lane = max(engine.lanes)
-        # fill, not np.zeros: calloc maps lazy zero pages, which would
-        # fault on the first pack instead of here
-        self._arena = np.empty(
-            max(
-                lane * hb * wb
-                for lane in engine.lanes for hb, wb in engine.hw_buckets
-            ),
-            np.float32,
+        self.depth = 2 if engine.dist.is_local else 1
+        size = max(
+            lane * hb * wb for lane in engine.lanes for hb, wb in engine.hw_buckets
         )
-        self._arena.fill(0)
+        self.arenas = tuple(np.empty(size, np.float32) for _ in range(self.depth))
+        for arena in self.arenas:
+            # fill, not np.zeros: calloc maps lazy zero pages, which would
+            # fault on the first pack instead of here
+            arena.fill(0)
+        self._free = collections.deque(self.arenas)  # under _cond
+        self._running = 0  # run_packed calls not yet returned, under _cond
+        self._runner = ThreadPoolExecutor(
+            max_workers=self.depth, thread_name_prefix=f"{self.name}-run"
+        )
         self._dispatcher = FailFast(
             target=self._dispatch_loop, daemon=True,
             name=f"{self.name}-dispatch", on_error=self._poison,
@@ -305,7 +316,11 @@ class ContinuousBatcher:
     def _dispatch_loop(self) -> None:
         while True:
             with self._cond:
-                batch, next_deadline = self._take_ready(self._clock())
+                # a free arena first, then the lane: while every arena is
+                # in flight, requests keep accumulating and the lane fills
+                batch, next_deadline = (
+                    self._take_ready(self._clock()) if self._free else (None, None)
+                )
                 if batch is None:
                     if self._stop.is_set():
                         return
@@ -315,6 +330,7 @@ class ContinuousBatcher:
                     with span("canny.wait"):
                         self._cond.wait(timeout=wait)
                     continue
+                arena = self._free.popleft()
             (hb, wb), taken = batch
             lane = self.engine.lane_for(len(taken))
             t_dispatch = self._clock()
@@ -322,29 +338,39 @@ class ContinuousBatcher:
                 t.t_dispatch = t_dispatch
             self.stats.record_occupancy(len(taken), lane)
             with span("canny.pack"):
-                # the last lane's run_packed has fetched its result, so no
-                # transfer still reads the arena this lane overwrites
                 packed, true_hw = pack_requests(
                     [self._images[id(t)] for t in taken], hb, wb, bb=lane,
-                    out=self._arena[: lane * hb * wb].reshape(lane, hb, wb),
+                    out=arena[: lane * hb * wb].reshape(lane, hb, wb),
                 )
             self.stats.record_arena_lane()
-            out = self.engine.run_packed(packed, true_hw)  # blocks on device
-            # bounded backlog: a slow drainer (or consumer) throttles the
-            # NEXT launch instead of results buffering without limit
-            put_cancellable(self._backlog, (taken, out), self._stop.is_set)
+            future = self._runner.submit(self._run, packed, true_hw)
+            self._backlog.put((taken, future, arena))
+
+    def _run(self, packed: np.ndarray, true_hw: np.ndarray) -> np.ndarray:
+        """One lane's launch, on a runner thread."""
+        with self._cond:
+            overlapped = self._running > 0
+            self._running += 1
+        if overlapped:
+            self.stats.record_overlapped_lane()
+        try:
+            return self.engine.run_packed(packed, true_hw)  # blocks on device
+        finally:
+            with self._cond:
+                self._running -= 1
 
     # -- completion plane ----------------------------------------------------
     def _drain_loop(self) -> None:
         while True:
             try:
-                taken, out = self._backlog.get(timeout=0.05)
+                taken, future, arena = self._backlog.get(timeout=0.05)
             except queue.Empty:
                 if self._stop.is_set() and self._backlog.empty():
                     return
                 if not self._dispatcher.is_alive() and self._backlog.empty():
                     return  # dispatcher died; its error is already posted
                 continue
+            out = future.result()  # a run_packed error poisons the batcher
             t_complete = self._clock()
             with self._cond:
                 for slot, ticket in enumerate(taken):
@@ -356,6 +382,9 @@ class ContinuousBatcher:
                     del self._images[id(ticket)]
                     self.completed += 1
                 self.engine.stats.requests += len(taken)
+                # run_packed has returned, so no transfer still reads the
+                # arena: only now may the next lane overwrite it
+                self._free.append(arena)
                 self._cond.notify_all()
 
     # -- lifecycle -----------------------------------------------------------
@@ -392,8 +421,11 @@ class ContinuousBatcher:
         self._stop.set()
         with self._cond:
             self._cond.notify_all()
-        self._dispatcher.join(timeout=timeout)
-        self._drainer.join(timeout=timeout)
+        try:
+            self._dispatcher.join(timeout=timeout)
+            self._drainer.join(timeout=timeout)
+        finally:
+            self._runner.shutdown(wait=False)
 
     def __enter__(self) -> "ContinuousBatcher":
         return self
@@ -407,3 +439,4 @@ class ContinuousBatcher:
                 self._cond.notify_all()
             self._dispatcher.join(timeout=5.0, reraise=False)
             self._drainer.join(timeout=5.0, reraise=False)
+            self._runner.shutdown(wait=False)

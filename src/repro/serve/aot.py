@@ -138,6 +138,7 @@ class AotCannyEngine:
         self.name = name
         self.stats = EngineStats()
         self._mesh_lock = None if dist.is_local else threading.Lock()
+        self._stats_lock = threading.Lock()
         if donate is None:
             donate = jax.devices()[0].platform in ("tpu", "gpu")
 
@@ -231,9 +232,10 @@ class AotCannyEngine:
         else:
             out = self._launch(exe, batch, true_hw)
         dt_ms = (time.perf_counter() - t0) * 1e3
-        self.stats.batches += 1
-        self.stats.padded_px += lane * hb * wb
-        self.stats.latencies_ms.append(dt_ms)
+        with self._stats_lock:  # a batcher runs two lanes at once
+            self.stats.batches += 1
+            self.stats.padded_px += lane * hb * wb
+            self.stats.latencies_ms.append(dt_ms)
         return out
 
     @staticmethod

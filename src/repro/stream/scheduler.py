@@ -94,8 +94,11 @@ class StreamStats:
     slot_occupancy: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=4096)
     )
-    # lanes the batcher's dispatch thread packed into its one host arena
+    # lanes the batcher's dispatch thread packed into its host arenas,
+    # and those launched while an earlier lane's run_packed had not yet
+    # returned
     arena_lanes: int = 0
+    overlapped_lanes: int = 0
     slo_ms: float | None = None
     slo_pass: int = 0
     slo_fail: int = 0
@@ -194,6 +197,10 @@ class StreamStats:
     def record_arena_lane(self) -> None:
         with self._lock:
             self.arena_lanes += 1
+
+    def record_overlapped_lane(self) -> None:
+        with self._lock:
+            self.overlapped_lanes += 1
 
     def latency_ms(self, q: float) -> float:
         """q-quantile of per-request enqueue→complete latency (the SLO

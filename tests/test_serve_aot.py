@@ -11,6 +11,7 @@ and the per-request SLO accounting grown onto ``StreamStats``.
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -25,6 +26,7 @@ from repro.serve import (
     default_lanes,
     infer_buckets,
 )
+from repro.serve.engine import pack_requests
 
 PARAMS = CannyParams(sigma=1.4, radius=2, low=0.08, high=0.2)
 
@@ -98,6 +100,41 @@ def test_run_packed_rejects_unwarmed_shape():
         engine.run_packed(
             np.zeros((1, 64, 64), np.float32), np.full((1, 2), 64, np.int32)
         )
+
+
+def test_run_packed_counts_every_launch_from_many_threads():
+    """A batcher calls ``run_packed`` from two runner threads at once:
+    under a short switch interval, no launch's count or pixels are lost
+    from ``EngineStats``."""
+    import sys
+
+    engine = make_engine(max_batch=2)
+    batch, true_hw = pack_requests([synthetic_image(30, 30, seed=7)] * 2, 32, 32, bb=2)
+    want = engine.run_packed(batch.copy(), true_hw)
+    before, px = engine.stats.batches, engine.stats.padded_px
+    n_threads, calls = 8, 12
+    outs: list = []
+
+    def launch():
+        for _ in range(calls):
+            outs.append(engine.run_packed(batch.copy(), true_hw))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=launch) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    n = n_threads * calls
+    assert len(outs) == n and all((o == want).all() for o in outs)
+    assert engine.stats.batches - before == n
+    assert engine.stats.padded_px - px == n * 2 * 32 * 32
+    assert len(engine.stats.latencies_ms) == n + 1
 
 
 # ---------------- the acceptance property ------------------------------------
@@ -292,7 +329,7 @@ def test_batcher_rejects_after_close():
 def test_batcher_validates_knobs():
     engine = make_engine()
     for kw in (
-        {"linger_ms": -1.0}, {"max_pending": 0}, {"backlog": 0}, {"timeout": 0.0},
+        {"linger_ms": -1.0}, {"max_pending": 0}, {"timeout": 0.0},
     ):
         with pytest.raises(ValueError):
             ContinuousBatcher(engine, **kw)
@@ -332,11 +369,11 @@ def test_batcher_scores_requests_against_slo():
         }
 
 
-def test_batcher_packs_every_lane_into_one_arena(monkeypatch):
+def test_batcher_packs_lanes_into_its_arenas(monkeypatch):
     """Lanes of two buckets, lane 4 each, the last one partial (flushed
-    at close), all packed into the arena made at construction: each
+    at close), all packed into the arenas made at construction: each
     result equals the synchronous wave's, and results kept from a lane
-    do not move when later lanes overwrite the arena."""
+    do not move when later lanes overwrite an arena."""
     from repro.serve import admission
 
     engine = make_engine(buckets=[(32, 32), (32, 64)], max_batch=4)
@@ -357,8 +394,11 @@ def test_batcher_packs_every_lane_into_one_arena(monkeypatch):
     want = [engine.process(g) for g in groups]
     kept, copies = [], []
     b = ContinuousBatcher(engine, linger_ms=60_000.0, timeout=60.0)
-    arena = b._arena
-    assert arena.dtype == np.float32 and arena.size == 4 * 32 * 64
+    arenas = b.arenas
+    assert b.depth == len(arenas) == 2  # a local engine: two lanes in flight
+    assert len({a.ctypes.data for a in arenas}) == 2
+    for arena in arenas:
+        assert arena.dtype == np.float32 and arena.size == 4 * 32 * 64
     with b:
         for g in groups[:3]:  # full lanes: only the fill trigger fires
             got = [t.result(timeout=60.0) for t in [b.submit(img) for img in g]]
@@ -367,15 +407,176 @@ def test_batcher_packs_every_lane_into_one_arena(monkeypatch):
         last = [b.submit(img) for img in groups[3]]
     kept.append([t.result() for t in last])
     copies.append([r.copy() for r in kept[-1]])
-    assert b._arena is arena
+    assert b.arenas is arenas
     for got, was, w in zip(kept, copies, want):
         for r, c, ref in zip(got, was, w):
             assert (r == c).all() and (r == ref).all()
+            assert not any(np.shares_memory(r, a) for a in arenas)
     assert len(outs) == 4
     for out in outs:
-        assert out.ctypes.data == arena.ctypes.data and np.shares_memory(out, arena)
+        assert out.ctypes.data in {a.ctypes.data for a in arenas}
+        assert any(np.shares_memory(out, a) for a in arenas)
     assert [o.shape for o in outs] == [(4, 32, 64), (4, 32, 32), (4, 32, 64), (4, 32, 32)]
     assert b.stats.arena_lanes == len(b.stats.slot_occupancy) == 4
+
+
+class _Gate:
+    """Wraps ``engine.run_packed``: call k enters, sets ``entered[k]``
+    and blocks until ``release[k]`` (call ``fail_at`` then raises instead
+    of waiting). ``returned`` holds the calls whose ``run_packed`` has
+    returned."""
+
+    def __init__(self, engine, n: int, fail_at: int | None = None):
+        self.run_packed = engine.run_packed
+        self.entered = [threading.Event() for _ in range(n)]
+        self.release = [threading.Event() for _ in range(n)]
+        self.returned: set[int] = set()
+        self.fail_at = fail_at
+        self._calls = 0
+        self._lock = threading.Lock()
+        engine.run_packed = self
+
+    def __call__(self, batch, true_hw):
+        with self._lock:
+            k = self._calls
+            self._calls += 1
+        self.entered[k].set()
+        if k == self.fail_at:
+            raise RuntimeError(f"lane {k} fell over")
+        assert self.release[k].wait(timeout=60.0), f"lane {k} never released"
+        out = self.run_packed(batch, true_hw)
+        with self._lock:
+            self.returned.add(k)
+        return out
+
+    def wait_entered(self, k: int) -> None:
+        assert self.entered[k].wait(timeout=60.0), f"lane {k} never launched"
+
+
+def test_batcher_keeps_two_lanes_in_flight(monkeypatch):
+    """A second full lane packs and enters ``run_packed`` while the first
+    is still inside it; a third does not pack until the first lane's
+    ``run_packed`` has returned, and then reuses its arena. No arena is
+    repacked while its lane is unfetched, and every result equals the
+    synchronous wave's."""
+    from repro.serve import admission
+
+    engine = make_engine(max_batch=2)
+    lanes = [
+        [synthetic_image(30, 30, seed=120 + 2 * k + i) for i in range(2)]
+        for k in range(3)
+    ]
+    want = [engine.process(lane) for lane in lanes]
+    gate = _Gate(engine, 3)
+    outs, unfetched_repacks = [], []
+    owner: dict[int, int] = {}  # arena address → the lane last packed there
+    real = admission.pack_requests
+
+    def spy(*args, **kw):
+        ptr = kw["out"].ctypes.data
+        if ptr in owner and owner[ptr] not in gate.returned:
+            unfetched_repacks.append((len(outs), owner[ptr]))
+        owner[ptr] = len(outs)
+        outs.append(kw["out"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(admission, "pack_requests", spy)
+    b = ContinuousBatcher(engine, linger_ms=60_000.0, timeout=60.0)
+    try:
+        tickets = [[b.submit(img) for img in lanes[0]]]
+        gate.wait_entered(0)
+        tickets.append([b.submit(img) for img in lanes[1]])
+        gate.wait_entered(1)  # lane 0 is still inside run_packed
+        assert b.stats.overlapped_lanes == 1
+        tickets.append([b.submit(img) for img in lanes[2]])
+        time.sleep(0.2)  # no arena is free: lane 2 must not pack
+        assert len(outs) == 2
+        assert all(t.t_dispatch is None for t in tickets[2])
+        gate.release[0].set()
+        gate.wait_entered(2)  # lane 0 fetched: its arena is lane 2's
+        assert 0 in gate.returned and 1 not in gate.returned
+        gate.release[1].set()
+        gate.release[2].set()
+        got = [[t.result(timeout=60.0) for t in lane] for lane in tickets]
+    finally:
+        for event in gate.release:
+            event.set()
+        b.close()
+    assert unfetched_repacks == []
+    assert outs[2].ctypes.data == outs[0].ctypes.data != outs[1].ctypes.data
+    assert b.stats.overlapped_lanes == 2  # lane 2 began while lane 1 ran
+    assert b.stats.arena_lanes == 3
+    for lane_got, lane_want in zip(got, want):
+        for g, w in zip(lane_got, lane_want):
+            assert (g == w).all()
+
+
+def test_failure_in_second_lane_in_flight_poisons_batcher():
+    """``run_packed`` raises on the second of two lanes in flight: the
+    first lane's tickets still resolve exactly, the second's fail with
+    the error, and ``close`` re-raises it."""
+    engine = make_engine(max_batch=2)
+    lanes = [
+        [synthetic_image(30, 30, seed=140 + 2 * k + i) for i in range(2)]
+        for k in range(2)
+    ]
+    want = engine.process(lanes[0])
+    gate = _Gate(engine, 2, fail_at=1)
+    b = ContinuousBatcher(engine, linger_ms=60_000.0, timeout=10.0)
+    try:
+        first = [b.submit(img) for img in lanes[0]]
+        gate.wait_entered(0)
+        second = [b.submit(img) for img in lanes[1]]
+        gate.wait_entered(1)  # raised while lane 0 is in flight
+        gate.release[0].set()
+        for t, w in zip(first, want):
+            assert (t.result(timeout=10.0) == w).all()
+        for t in second:
+            with pytest.raises(RuntimeError, match="lane 1 fell over"):
+                t.result(timeout=10.0)
+    finally:
+        for event in gate.release:
+            event.set()
+    with pytest.raises(RuntimeError, match="lane 1 fell over"):
+        b.close()
+
+
+def test_batcher_over_a_mesh_engine_runs_one_lane_at_a_time():
+    """A mesh engine serialises ``run_packed`` through its fetch, so the
+    batcher keeps one arena and never starts a lane while another runs:
+    the second full lane waits until the first returns."""
+    from repro.core.patterns.dist import Dist, auto_mesh
+
+    mesh = auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
+    engine = make_engine(
+        max_batch=2, backend="jnp",
+        dist=Dist(mesh=mesh, batch_axes=("data",), space_axis="model"),
+    )
+    lanes = [
+        [synthetic_image(30, 30, seed=160 + 2 * k + i) for i in range(2)]
+        for k in range(2)
+    ]
+    gate = _Gate(engine, 2)
+    b = ContinuousBatcher(engine, linger_ms=60_000.0, timeout=60.0)
+    try:
+        assert b.depth == len(b.arenas) == 1
+        tickets = [[b.submit(img) for img in lane] for lane in lanes]
+        gate.wait_entered(0)
+        time.sleep(0.2)
+        assert not gate.entered[1].is_set()  # one lane in flight
+        gate.release[0].set()
+        gate.wait_entered(1)
+        gate.release[1].set()
+        got = [[t.result(timeout=60.0) for t in lane] for lane in tickets]
+    finally:
+        for event in gate.release:
+            event.set()
+        b.close()
+    assert b.stats.overlapped_lanes == 0
+    assert b.stats.arena_lanes == 2
+    for lane_got, lane in zip(got, lanes):
+        for g, img in zip(lane_got, lane):
+            assert (g == canny_reference(img, PARAMS)).all()
 
 
 # ---------------- scheduler integration --------------------------------------

@@ -1,5 +1,6 @@
-"""Host spans (``core/spans.py``) in the dispatch thread and the stream
-workers, read back from a profiler trace captured on the CPU.
+"""Host spans (``core/spans.py``) in the batcher's dispatch and runner
+threads and the stream workers, read back from a profiler trace captured
+on the CPU.
 
 The contract the trace's readers rest on: one span of each phase per lane
 or per frame, and no two phase spans overlapping on one thread, so that a
@@ -79,8 +80,12 @@ def test_a_lane_has_one_span_of_each_phase(tmp_path):
     assert {p: counts.get(p) for p in LANE_PHASES} == dict.fromkeys(LANE_PHASES, 1)
     assert counts.get("canny.wait", 0) >= 1  # idle before the first submit
     (dispatch,) = [s for s in threads if any(n == "canny.pack" for _, _, n in s)]
-    lane = [n for _, _, n in dispatch if n != "canny.wait"]
-    assert lane == list(LANE_PHASES)  # in this order, on the dispatch thread
+    assert [n for _, _, n in dispatch if n != "canny.wait"] == ["canny.pack"]
+    (runner,) = [s for s in threads if any(n == "canny.fetch" for _, _, n in s)]
+    # in this order, on the runner thread the dispatch thread handed the lane
+    assert [n for _, _, n in runner] == list(LANE_PHASES[1:])
+    (pack_end,) = [e for _, e, n in dispatch if n == "canny.pack"]
+    assert runner[0][0] >= pack_end
     _assert_disjoint(threads)
 
 
